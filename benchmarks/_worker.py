@@ -23,7 +23,8 @@ import time  # noqa: E402
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core.listrank import (IndirectionSpec, ListRankConfig,  # noqa
                                  analysis, instances, rank_list_with_stats)
 from repro.obs import json_safe_stats  # noqa: E402
@@ -33,8 +34,9 @@ MACHINES = {"supermuc": analysis.SUPERMUC, "tpu": analysis.TPU_V5E_ICI,
 
 
 def main():
+    enable_compile_cache()
     rows, cols = spec.get("mesh") or (1, spec["p"])
-    mesh = compat.make_mesh((rows, cols), ("row", "col"))
+    mesh = make_mesh((rows, cols), ("row", "col"))
     n = spec["n_per_pe"] * spec["p"]
     inst = spec.get("instance", "list")
     if inst == "list":

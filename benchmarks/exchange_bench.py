@@ -43,7 +43,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core.listrank import analysis, instances, introspect  # noqa: E402
 from repro.core.listrank.api import CHASE_WIRE_WORDS  # noqa: E402
 from repro.core.listrank.config import IndirectionSpec  # noqa: E402
@@ -86,13 +87,14 @@ def build_fn(mesh, plan, caps, keys, chain=1):
             acc = acc + jnp.sum(jnp.where(dv, d["ruler"], 0))
         return acc
 
-    return compat.shard_map(
-        fn, mesh, in_specs=tuple(P(AXES) for _ in range(len(keys) + 2)),
-        out_specs=P())
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(P(AXES) for _ in range(len(keys) + 2)),
+        out_specs=P(), check_vma=False)
 
 
 def main():
-    mesh = compat.make_mesh(MESH, AXES)
+    enable_compile_cache()
+    mesh = make_mesh(MESH, AXES)
     n = NPE * P_BENCH
     payload, dest, valid, q = chase_queue(n, P_BENCH)
     keys = sorted(payload.keys())

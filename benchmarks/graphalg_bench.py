@@ -44,7 +44,8 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
 from _common import modeled_large_p  # noqa: E402
-from repro import compat  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core import graphalg  # noqa: E402
 from repro.core.listrank import ListRankConfig, instances  # noqa: E402
 
@@ -73,7 +74,8 @@ def cross_fraction(edges, p, n):
 
 
 def main():
-    mesh = compat.make_mesh(MESH, AXES)
+    enable_compile_cache()
+    mesh = make_mesh(MESH, AXES)
     cfg = ListRankConfig(srs_rounds=1, local_contraction=True)
     results = {"quick": QUICK, "p": P_BENCH, "n_nodes": N_NODES,
                "edge_factor": EDGE_FACTOR, "p_model": P_MODEL,

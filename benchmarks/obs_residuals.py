@@ -25,6 +25,7 @@ sys.path.insert(0, str(HERE.parent / "src"))
 
 import numpy as np  # noqa: E402
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.listrank import (ListRankConfig, instances,  # noqa: E402
                                  rank_list_with_stats, sim_mesh)
 from repro.core.listrank import resume as resume_lib  # noqa: E402
@@ -55,6 +56,7 @@ def make_instance(fam_kw, n):
 
 
 def main():
+    enable_compile_cache()
     RESULTS.mkdir(exist_ok=True)
     n = NPE * P
     cfg = ListRankConfig(algorithm="srs", srs_rounds=2,

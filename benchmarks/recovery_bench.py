@@ -31,6 +31,7 @@ RESULTS = HERE / "results"
 sys.path.insert(0, str(HERE.parent / "src"))
 
 import numpy as np  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 QUICK = os.environ.get("BENCH_QUICK", "0") == "1"
 P = 8 if QUICK else 16
@@ -60,6 +61,7 @@ def _euler(n, locality):
 
 
 def main():
+    enable_compile_cache()
     from repro.core.listrank import (FaultSpec, ListRankConfig,
                                      rank_list_with_stats, sim_mesh, tuner)
     from repro.core.listrank.exchange import MeshPlan

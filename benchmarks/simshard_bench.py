@@ -39,6 +39,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.listrank import (ListRankConfig, instances,  # noqa: E402
                                  introspect, rank_list_seq,
                                  rank_list_with_stats, sim_mesh)
@@ -102,6 +103,7 @@ def bench_p(p: int) -> dict:
 
 
 def main():
+    enable_compile_cache()
     print("name,us_per_call,derived")
     rows = [bench_p(p) for p in P_SIZES]
     RESULTS.mkdir(exist_ok=True)

@@ -47,7 +47,8 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
 from _common import modeled_large_p  # noqa: E402
-from repro import compat  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core import treealg  # noqa: E402
 from repro.core.listrank import ListRankConfig, instances  # noqa: E402
 
@@ -70,7 +71,8 @@ def timed(fn, iters):
 
 
 def main():
-    mesh = compat.make_mesh(MESH, AXES)
+    enable_compile_cache()
+    mesh = make_mesh(MESH, AXES)
     cfg = ListRankConfig(srs_rounds=2, local_contraction=True)
     results = {"quick": QUICK, "p": P_BENCH, "n_nodes": N_NODES,
                "p_model": P_MODEL, "families": [], "batch": {}}

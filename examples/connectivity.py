@@ -21,7 +21,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core import graphalg, treealg  # noqa: E402
 from repro.core.listrank import ListRankConfig, instances  # noqa: E402
 
@@ -43,8 +44,9 @@ def union_find(n, edges):
 
 
 def main():
+    enable_compile_cache()
     p = len(jax.devices())
-    mesh = compat.make_mesh((p,), ("pe",))
+    mesh = make_mesh((p,), ("pe",))
     cfg = ListRankConfig(srs_rounds=1, local_contraction=True)
 
     n, e = 1 << 11, 1 << 12

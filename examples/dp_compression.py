@@ -24,13 +24,13 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.runtime import compression  # noqa: E402
 
 
 def main():
     p = len(jax.devices())
-    mesh = compat.make_mesh((p,), ("data",))
+    mesh = make_mesh((p,), ("data",))
     dim = 512
     rng = np.random.default_rng(0)
     w_true = jnp.asarray(rng.normal(size=(dim,)), jnp.float32)
@@ -43,7 +43,7 @@ def main():
 
         @jax.jit
         @functools.partial(
-            compat.shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(P(), P("data"), P("data"), P("data")),
             out_specs=(P(), P("data")))
         def step(w, x, y, err):

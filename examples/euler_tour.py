@@ -19,14 +19,16 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
-from repro import compat
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.core.listrank import (ListRankConfig, instances,
                                  rank_list_with_stats)
 
 
 def main():
+    enable_compile_cache()
     p = len(jax.devices())
-    mesh = compat.make_mesh((p,), ("pe",))
+    mesh = make_mesh((p,), ("pe",))
     n_nodes = 4097
     succ, rank, arcs = instances.gen_euler_tour(n_nodes, seed=3,
                                                 locality=True)

@@ -2,6 +2,10 @@
 
   PYTHONPATH=src python examples/quickstart.py
 
+On CPU it runs on 8 virtual PEs unless XLA_FLAGS says otherwise
+(``XLA_FLAGS=--xla_force_host_platform_device_count=4`` for a 2x2 grid);
+on a TPU every chip is one PE (1 chip: a 1x1 grid, the 2x2 host: 2x2).
+
 Builds the paper's List(n, gamma) instance, runs sparse-ruling-set with
 spawning (2 rounds + pointer-doubling base case, local contraction on,
 reversal avoided via the §2.5 postprocess) on a device mesh, verifies
@@ -17,21 +21,22 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import math
 
-import jax
 import numpy as np
 
-from repro import compat
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_pe_mesh
 from repro.core.listrank import (IndirectionSpec, ListRankConfig, analysis,
                                  instances, rank_list_seq,
                                  rank_list_with_stats)
 
 
 def main():
-    p = len(jax.devices())
-    mesh = compat.make_mesh((2, p // 2), ("row", "col"))
+    enable_compile_cache()
+    mesh = make_pe_mesh()
+    p = mesh.size
     n = 1 << 16
     print(f"ranking a {n}-element random list on {p} PEs "
-          f"(grid indirection {2}x{p // 2})")
+          f"(grid indirection {mesh.shape['row']}x{mesh.shape['col']})")
     succ, rank = instances.gen_list(n, gamma=1.0, seed=0)
 
     cfg = ListRankConfig(srs_rounds=2, local_contraction=True,

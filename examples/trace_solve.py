@@ -32,6 +32,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np  # noqa: E402
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.listrank import (ListRankConfig, instances,  # noqa: E402
                                  rank_list_seq, rank_list_with_stats,
                                  sim_mesh)
@@ -39,6 +40,7 @@ from repro import obs  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     out_path = sys.argv[1] if len(sys.argv) > 1 else "trace.json"
     p, n = 8, 1 << 14
     succ, rank = instances.gen_list(n, gamma=1.0, seed=0)

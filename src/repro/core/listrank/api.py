@@ -27,7 +27,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.listrank import local as local_lib
 from repro.core.listrank import store as store_lib
@@ -357,6 +357,79 @@ def _jitted_solver(mesh, plan, cfg, specs, m):
         out_specs=out_specs)
 
 
+def _resolve(n: int, mesh, pe_axes, cfg: ListRankConfig,
+             indirection: IndirectionSpec | None):
+    """What the front door derives before any data moves: the backend
+    (and its mesh), the PE axes, the routing plan, the resolved config
+    (``algorithm="auto"`` decided) and the per-PE size m."""
+    pe_axes = tuple(pe_axes) if pe_axes is not None else tuple(mesh.axis_names)
+    backend, mesh = transport_lib.resolve_backend(cfg.backend, mesh, pe_axes)
+    if backend == "simshard":
+        transport_lib.check_sim_config(cfg)
+    if indirection is None and cfg.auto_indirection:
+        axis_sizes = tuple(mesh.shape[a] for a in pe_axes)
+        indirection = tuner.choose_indirection(cfg, pe_axes, axis_sizes, n)
+    plan = MeshPlan.from_mesh(mesh, pe_axes, indirection,
+                              wire_packing=cfg.wire_packing,
+                              pallas_pack=cfg.use_pallas_pack,
+                              telemetry=cfg.telemetry)
+    p = plan.p
+    if n % p != 0:
+        raise ValueError(f"n={n} must be divisible by p={p} (pad the input)")
+    m = n // p
+    if cfg.algorithm == "auto":
+        # Corollary-1 regime check: PD below the efficiency threshold.
+        cfg = cfg.with_(algorithm=tuner.choose_algorithm(
+            cfg, p, plan.indirection.depth, m))
+    return backend, mesh, pe_axes, plan, cfg, m
+
+
+def lower_stages(n: int, mesh, pe_axes: Sequence[str] | None = None,
+                 cfg: ListRankConfig | None = None,
+                 indirection: IndirectionSpec | None = None,
+                 term_bound: int = 1, weight_dtype=jnp.int32):
+    """Lower every stage program of a straight-through
+    :func:`rank_list_with_stats` solve of ``n`` elements from shapes
+    alone. Returns ``[(stage label, jax.stages.Lowered)]``.
+    ``term_bound`` is the most list terminals on one PE (1 for a single
+    list), which the front door counts from the instance.
+
+    Nothing runs and no array is placed, so ``mesh`` may hold described
+    devices (``jax.experimental.topologies``); ``.compile()`` on each
+    result then says what the chip's compiler says, and its
+    ``memory_analysis()`` what the program needs on the device. Built
+    with the front door's own plan, specs and jitted stage runners, so
+    on a real mesh the executables are the ones the solve will use.
+    """
+    cfg = cfg or ListRankConfig()
+    backend, mesh, pe_axes, plan, cfg, m = _resolve(n, mesh, pe_axes, cfg,
+                                                    indirection)
+    if backend != "mesh":
+        raise ValueError("lower_stages needs a device mesh")
+    wdt = canonical_weight_dtype(weight_dtype)
+    specs = build_specs(cfg, plan, m, n, term_bound)
+    sched = resume_lib.schedule_for(cfg)
+    sharded = NamedSharding(mesh, P(pe_axes))
+
+    def placed(like):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharded), like)
+
+    succ = placed(jax.ShapeDtypeStruct((n,), jnp.int32))
+    rank = placed(jax.ShapeDtypeStruct((n,), wdt))
+    seed = jax.ShapeDtypeStruct((), jnp.int32)
+    out = []
+    for idx, stage in enumerate(sched):
+        state = (placed(resume_lib.boundary_template(
+            sched, idx, cfg, specs, m, plan.p, wdt)) if idx else None)
+        args = resume_lib._stage_args(stage, state, succ, rank, seed)
+        runner = resume_lib._jitted_stage(
+            mesh, plan, cfg, stage, resume_lib._stage_specs(stage, specs), m)
+        out.append((stage.label, runner.lower(*args)))
+    return out
+
+
 def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
                          cfg: ListRankConfig | None = None,
                          indirection: IndirectionSpec | None = None,
@@ -393,26 +466,10 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
     bit-identical with tracing on or off.
     """
     cfg = cfg or ListRankConfig()
-    pe_axes = tuple(pe_axes) if pe_axes is not None else tuple(mesh.axis_names)
-    backend, mesh = transport_lib.resolve_backend(cfg.backend, mesh, pe_axes)
-    if backend == "simshard":
-        transport_lib.check_sim_config(cfg)
     n = succ.shape[0]
-    if indirection is None and cfg.auto_indirection:
-        axis_sizes = tuple(mesh.shape[a] for a in pe_axes)
-        indirection = tuner.choose_indirection(cfg, pe_axes, axis_sizes, n)
-    plan = MeshPlan.from_mesh(mesh, pe_axes, indirection,
-                              wire_packing=cfg.wire_packing,
-                              pallas_pack=cfg.use_pallas_pack,
-                              telemetry=cfg.telemetry)
+    backend, mesh, pe_axes, plan, cfg, m = _resolve(n, mesh, pe_axes, cfg,
+                                                    indirection)
     p = plan.p
-    if n % p != 0:
-        raise ValueError(f"n={n} must be divisible by p={p} (pad the input)")
-    m = n // p
-    if cfg.algorithm == "auto":
-        # Corollary-1 regime check: PD below the efficiency threshold.
-        cfg = cfg.with_(algorithm=tuner.choose_algorithm(
-            cfg, p, plan.indirection.depth, m))
     s_host = None
     if term_bound is None:
         s_host = np.asarray(jax.device_get(succ))

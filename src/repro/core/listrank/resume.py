@@ -661,7 +661,8 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                 injector.crash_before(stage.kind, stage.level)
             runner = _jitted_stage(mesh, plan, cfg, stage,
                                    _stage_specs(stage, specs), m)
-            args = _stage_args(stage, state, succ_d, rank_d, seed)
+            args = _stage_args(stage, state, succ_d, rank_d,
+                               jnp.int32(seed))
             t0 = time.time()
             out = runner(*args)
             jax.block_until_ready(jax.tree.leaves(out))
@@ -827,7 +828,7 @@ def _stage_args(stage: Stage, state, succ_d, rank_d, seed):
     if stage.kind == "prep":
         return (succ_d, rank_d)
     if stage.kind == "descend":
-        return (state, jnp.int32(seed))
+        return (state, seed)
     if stage.kind == "post":
         return (state, succ_d, rank_d)
     return (state,)

@@ -32,7 +32,7 @@ from jax import lax
 from repro.core.listrank import store as store_lib
 from repro.core.listrank.config import ListRankConfig
 from repro.core.listrank.doubling import allgather_solve, doubling_solve
-from repro.core.listrank.exchange import (MeshPlan, compact_queue,
+from repro.core.listrank.exchange import (MeshPlan, compact_queue, prefix_sum,
                                           remote_gather, route_compact)
 from repro.obs import telemetry as tele_lib
 
@@ -201,7 +201,7 @@ def _launch_from_perm(st, visited, is_ruler, perm, r_target):
     cap = st.ids.shape[0]
     pidx = jnp.minimum(perm, cap - 1)
     ok = (perm < cap) & st.valid[pidx] & ~visited[pidx]
-    cnt = jnp.cumsum(ok.astype(jnp.int32))
+    cnt = prefix_sum(ok.astype(jnp.int32))
     sel = ok & (cnt <= r_target)
     consumed = jnp.minimum(
         jnp.searchsorted(cnt, r_target, side="left").astype(jnp.int32) + 1,
@@ -218,7 +218,7 @@ def _spawn(st, visited, is_ruler, perm, perm_pos, window, k):
     w = lax.dynamic_slice(perm, (perm_pos,), (window,))
     widx = jnp.minimum(w, cap - 1)
     ok = (w < cap) & st.valid[widx] & ~visited[widx]
-    cnt = jnp.cumsum(ok.astype(jnp.int32))
+    cnt = prefix_sum(ok.astype(jnp.int32))
     sel = ok & (cnt <= k)
     avail = cnt[-1]
     spawned = jnp.minimum(k, avail)

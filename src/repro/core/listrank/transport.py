@@ -48,7 +48,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 # --------------------------------------------------------------------------
 # simulated-collective markers
@@ -275,9 +274,9 @@ def device_run(mesh, pe_axes: Sequence[str], fn, in_specs, out_specs):
     """
     pe_axes = tuple(pe_axes)
     if not is_sim(mesh):
-        return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                        out_specs=out_specs,
-                                        check_vma=False))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs,
+                                     check_vma=False))
 
     sizes = tuple(mesh.shape[a] for a in pe_axes)
     flat = 1

@@ -3,17 +3,26 @@ kernel and the XLA fallback, with the interpret-mode switch for CPU."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.mailbox_pack import kernel as _kernel
 from repro.kernels.mailbox_pack import ref as _ref
 
-#: per-core VMEM budget for the resident working set (planes + buffer).
-VMEM_BUDGET = 12 * 1024 * 1024
+#: bytes of the VMEM-resident send buffer the kernel takes: half of
+#: v5e's 128 MiB VMEM. The TPU compiler accepts a buffer of this size
+#: and refuses one of twice it (tests/test_tpu_compile.py compiles both).
+VMEM_BUDGET = 64 * 1024 * 1024
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def uses_kernel(n_cols: int, n_rows: int) -> bool:
+    """Whether :func:`mailbox_pack` with ``use_pallas=True`` runs the
+    Pallas kernel for a ``(n_cols, n_rows)`` send buffer (else the XLA
+    scatter reference)."""
+    rows = -(-n_rows // _kernel.LANES)
+    return 4 * n_cols * rows * _kernel.LANES <= VMEM_BUDGET
 
 
 def mailbox_pack(cols, slots: jax.Array, n_rows: int,
@@ -22,12 +31,9 @@ def mailbox_pack(cols, slots: jax.Array, n_rows: int,
 
     out[w, slots[i]] = cols[w][i] for messages with slots[i] < n_rows;
     everything else is zero (invalid on the wire). ``use_pallas`` routes
-    through the fused VMEM kernel when the working set fits.
+    through the fused kernel when :func:`uses_kernel` admits the buffer.
     """
-    q = slots.shape[0]
-    w = len(cols)
-    working_set = 4 * (q * (w + 1) + n_rows * w)
-    if use_pallas and working_set <= VMEM_BUDGET:
+    if use_pallas and uses_kernel(len(cols), n_rows):
         return _kernel.mailbox_pack_pallas(tuple(cols), slots, n_rows,
                                            interpret=not _on_tpu())
     return _ref.mailbox_pack_ref(cols, slots, n_rows)

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
                 n_chunks: int, has_skip: bool, d_ref=None):
@@ -127,7 +125,7 @@ def ssd_scan_pallas(x, dt, A, B, C, D=None, *, chunk=128, interpret=True):
                                lambda b_, h_, ic: (b_, h_, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((bt, h, l, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -13,7 +13,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.params import spec
 
 # ---------------------------------------------------------------------------
@@ -562,7 +561,7 @@ def moe_ffn_ep(p, x, cfg, ctx):
     in_specs = (dp_spec, P(None, None), w_spec, w_spec, w_spec_t,
                 shared_specs if cfg.num_shared_experts else P())
     shared_p = p.get("shared", jnp.zeros((), x.dtype))
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(dp_spec, P()),

@@ -15,7 +15,6 @@ from typing import Mapping, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 #: default rule table. Each logical axis maps to candidates in
 #: preference order; () means replicated.
@@ -97,7 +96,7 @@ def resolve_spec(shape: Sequence[int], axes: Sequence[str | None],
 def tree_shardings(spec_tree, mesh: Mesh, rules=None,
                    report: ResolveReport | None = None):
     """Map a tree of ParamSpec-likes (.shape/.axes) to NamedShardings."""
-    flat, treedef = compat.tree_flatten_with_path(
+    flat, treedef = jax.tree.flatten_with_path(
         spec_tree, is_leaf=lambda x: hasattr(x, "axes"))
     out = []
     for path, leaf in flat:

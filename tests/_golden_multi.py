@@ -18,7 +18,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
-from repro import compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core.listrank import rank_list_with_stats  # noqa: E402
 
 import _simshard_cases as cases_lib  # noqa: E402
@@ -26,7 +26,7 @@ import _simshard_cases as cases_lib  # noqa: E402
 
 def main():
     write = "--write" in sys.argv[1:]
-    mesh = compat.make_mesh(cases_lib.SHAPE, cases_lib.AXES)
+    mesh = make_mesh(cases_lib.SHAPE, cases_lib.AXES)
     if write:
         cases_lib.GOLDEN_DIR.mkdir(exist_ok=True)
     for name, succ, rank, cfg in cases_lib.golden_cases():

@@ -29,7 +29,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core.listrank import (IndirectionSpec, ListRankConfig,  # noqa
                                  instances, introspect, rank_list_seq,
                                  rank_list_with_stats)
@@ -49,7 +49,7 @@ def check(name, ok):
 
 
 def _mesh():
-    return compat.make_mesh((2, 4), AXES)
+    return make_mesh((2, 4), AXES)
 
 
 # --------------------------------------------------------------------------
@@ -84,9 +84,9 @@ def suite_exchange():
 
         args = [jnp.asarray(payload[k]) for k in keys] + [
             jnp.asarray(dest), jnp.asarray(valid)]
-        m = jax.jit(compat.shard_map(
-            fn, mesh, in_specs=tuple(P_ALL for _ in args),
-            out_specs=({k: P_ALL for k in keys}, P_ALL, P())))
+        m = jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=tuple(P_ALL for _ in args),
+            out_specs=({k: P_ALL for k in keys}, P_ALL, P()), check_vma=False))
         d, dv, left = m(*args)
         return {k: np.asarray(v) for k, v in d.items()}, \
             np.asarray(dv), int(left)
@@ -131,9 +131,9 @@ def suite_exchange():
 
     args = [jnp.asarray(payload[k]) for k in keys] + [
         jnp.asarray(dest), jnp.asarray(valid)]
-    m = jax.jit(compat.shard_map(
-        drain, mesh, in_specs=tuple(P_ALL for _ in args),
-        out_specs=(P(None, AXES), P(None, AXES), P())))
+    m = jax.jit(jax.shard_map(
+        drain, mesh=mesh, in_specs=tuple(P_ALL for _ in args),
+        out_specs=(P(None, AXES), P(None, AXES), P()), check_vma=False))
     ia_r, dv_r, rest = m(*args)
     ia_r, dv_r = np.asarray(ia_r), np.asarray(dv_r)
     check("overflow drain",
@@ -155,9 +155,9 @@ def suite_exchange():
             req_cap=[n] * 2, resp_cap=[n] * 2, dedup=True)
         return out, answered
 
-    m = jax.jit(compat.shard_map(
-        gather, mesh, in_specs=(P_ALL, P_ALL),
-        out_specs=({"val": P_ALL}, P_ALL)))
+    m = jax.jit(jax.shard_map(
+        gather, mesh=mesh, in_specs=(P_ALL, P_ALL),
+        out_specs=({"val": P_ALL}, P_ALL), check_vma=False))
     out, answered = m(jnp.asarray(targets), jnp.asarray(gvalid))
     check("gather topo dedup",
           np.array_equal(np.asarray(answered), gvalid)
@@ -175,9 +175,9 @@ def suite_exchange():
                                     leaves[-1])
                 return d, dv
 
-            m = compat.shard_map(
-                fn, mesh, in_specs=tuple(P_ALL for _ in args),
-                out_specs=({k: P_ALL for k in keys}, P_ALL))
+            m = jax.shard_map(
+                fn, mesh=mesh, in_specs=tuple(P_ALL for _ in args),
+                out_specs=({k: P_ALL for k in keys}, P_ALL), check_vma=False)
             counts = introspect.collective_counts(m, *args)
             check(f"collectives {name} packed={packed}",
                   counts.get("all_to_all", 0) == per_hop * hops)
@@ -355,7 +355,7 @@ def suite_faultinject():
     s, r, cfg = next((s, r, c) for nm, s, r, c in golden_cases()
                      if nm == name)
     gold = load_golden(name)
-    dev_mesh = compat.make_mesh(G_SHAPE, G_AXES)
+    dev_mesh = make_mesh(G_SHAPE, G_AXES)
     backends = {"mesh": lambda: dev_mesh,
                 "sim": lambda: sim_mesh(G_SHAPE, G_AXES)}
 
@@ -420,7 +420,7 @@ def suite_obs():
     name = "list-g1-s1"
     s, r, cfg = next((s, r, c) for nm, s, r, c in golden_cases()
                      if nm == name)
-    mesh = compat.make_mesh(G_SHAPE, G_AXES)
+    mesh = make_mesh(G_SHAPE, G_AXES)
     tr = obs.Tracer(meta={"name": f"smoke-obs/{name}", "backend": "mesh"})
     sf, rf, stats = rank_list_with_stats(s, r, mesh, cfg=cfg, tracer=tr)
     check("mesh golden bytes identical with tracing on",

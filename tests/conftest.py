@@ -15,6 +15,33 @@ jax.config.update("jax_platform_name", "cpu")
 import pytest  # noqa: E402
 
 
+def _mapping_budget() -> int:
+    """A third of the kernel's per-process limit on memory mappings."""
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read()) // 3
+    except (OSError, ValueError):
+        return 0
+
+
+_MAPPING_BUDGET = _mapping_budget()
+
+
+@pytest.fixture(autouse=True)
+def _bound_memory_mappings():
+    """Every XLA:CPU executable holds memory mappings until its jit
+    cache entry goes. A module that compiles hundreds of programs
+    (test_listrank.py: a new shape per hypothesis example) reaches
+    vm.max_map_count, and the next compile then dies inside XLA:CPU
+    with SIGSEGV or SIGABRT. Clear the caches after any test that
+    leaves the process above a third of the limit."""
+    yield
+    if _MAPPING_BUDGET:
+        with open("/proc/self/maps") as f:
+            if sum(1 for _ in f) > _MAPPING_BUDGET:
+                jax.clear_caches()
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_between_modules():
     """XLA:CPU accumulates JIT-compiled executables across this large

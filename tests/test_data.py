@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.data import packing, pipeline
 
 
@@ -55,7 +55,7 @@ def test_segment_metadata_is_list_ranking():
 
 def test_distributed_matches_oracle():
     import jax
-    mesh = compat.make_mesh((1,), ("pe",))
+    mesh = make_mesh((1,), ("pe",))
     docs = _docs(7, n_docs=30)
     packed = packing.pack_documents(docs, row_len=48)
     t1, a1 = packing.segment_metadata(packed)

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.listrank import introspect
 from repro.core.listrank.config import IndirectionSpec
 from repro.core.listrank.exchange import (MeshPlan, WireFormat,
@@ -20,7 +20,7 @@ from repro.kernels.mailbox_pack import ops as mp_ops
 
 
 def mesh1():
-    return compat.make_mesh((1,), ("pe",))
+    return make_mesh((1,), ("pe",))
 
 
 def plan1(packed=True, pallas=False):
@@ -120,12 +120,12 @@ def _run_route(plan, cap, payload, dest, valid, track_src=False):
         return d, dv, left
     keys = sorted(payload.keys())
     args = [payload[k] for k in keys] + [dest, valid]
-    m = compat.shard_map(fn, mesh1(),
-                         in_specs=tuple(P("pe") for _ in args),
-                         out_specs=(
-                             {k: P("pe") for k in keys + (
-                                 ["src"] if track_src else [])},
-                             P("pe"), P()))
+    m = jax.shard_map(fn, mesh=mesh1(),
+                      in_specs=tuple(P("pe") for _ in args),
+                      out_specs=(
+                          {k: P("pe") for k in keys + (
+                              ["src"] if track_src else [])},
+                          P("pe"), P()), check_vma=False)
     return m(*args)
 
 
@@ -175,7 +175,7 @@ def test_route_collectives_per_hop(packed, per_hop):
 
     for mesh, axes, ind, hops in [
             (mesh1(), ("pe",), None, 1),
-            (compat.make_mesh((1, 1), ("row", "col")), ("row", "col"),
+            (make_mesh((1, 1), ("row", "col")), ("row", "col"),
              IndirectionSpec.grid(("row", "col")), 2)]:
         plan = MeshPlan.from_mesh(mesh, axes, ind, wire_packing=packed)
 
@@ -187,9 +187,10 @@ def test_route_collectives_per_hop(packed, per_hop):
 
         args = [payload[k] for k in keys] + [
             jnp.zeros(q, jnp.int32), jnp.ones(q, bool)]
-        m = compat.shard_map(fn, mesh,
-                             in_specs=tuple(P(axes) for _ in args),
-                             out_specs=({k: P(axes) for k in keys}, P(axes)))
+        m = jax.shard_map(fn, mesh=mesh,
+                          in_specs=tuple(P(axes) for _ in args),
+                          out_specs=({k: P(axes) for k in keys}, P(axes)),
+                          check_vma=False)
         counts = introspect.collective_counts(m, *args)
         assert counts.get("all_to_all", 0) == per_hop * hops, counts
 
@@ -220,8 +221,10 @@ def test_route_compact_matches_route_plus_compact():
     specs = tuple(P("pe") for _ in args)
     ospec = ({k: P("pe") for k in keys}, P("pe"),
              {k: P("pe") for k in keys}, P("pe"), P("pe"), P())
-    a = compat.shard_map(fused, mesh1(), in_specs=specs, out_specs=ospec)(*args)
-    b = compat.shard_map(legacy, mesh1(), in_specs=specs, out_specs=ospec)(*args)
+    a = jax.shard_map(fused, mesh=mesh1(), in_specs=specs, out_specs=ospec,
+                      check_vma=False)(*args)
+    b = jax.shard_map(legacy, mesh=mesh1(), in_specs=specs, out_specs=ospec,
+                      check_vma=False)(*args)
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
@@ -245,9 +248,9 @@ def test_remote_gather_p1(packed, dedup):
             req_cap=q, resp_cap=q, dedup=dedup)
         return out, answered
 
-    m = compat.shard_map(fn, mesh1(), in_specs=(P("pe"), P("pe")),
-                         out_specs=({"val": P("pe"), "flag": P("pe")},
-                                    P("pe")))
+    m = jax.shard_map(fn, mesh=mesh1(), in_specs=(P("pe"), P("pe")),
+                      out_specs=({"val": P("pe"), "flag": P("pe")},
+                                 P("pe")), check_vma=False)
     out, answered = m(targets, valid)
     np.testing.assert_array_equal(np.asarray(answered), np.asarray(valid))
     got = np.asarray(out["val"])[np.asarray(valid)]
@@ -293,10 +296,10 @@ def test_request_reply_owner_computed_addressing():
                                                   mode="drop")
         return out, aux, st["leftover"], st["sent"]
 
-    m = compat.shard_map(fn, mesh1(),
-                         in_specs=(P("pe"), P("pe"), P("pe")),
-                         out_specs=(P("pe"), P(), P(), P()),
-                         check_vma=False)
+    m = jax.shard_map(fn, mesh=mesh1(),
+                      in_specs=(P("pe"), P("pe"), P("pe")),
+                      out_specs=(P("pe"), P(), P(), P()),
+                      check_vma=False)
     out, aux, leftover, sent = m(slot, val, valid)
     np.testing.assert_array_equal(
         np.asarray(out), np.where(np.asarray(valid), 2 * np.asarray(val),
@@ -327,9 +330,10 @@ def test_route_collective_payload_bytes_exact():
 
     args = [payload[k] for k in keys] + [
         jnp.zeros(q, jnp.int32), jnp.ones(q, bool)]
-    m = compat.shard_map(fn, mesh1(),
-                         in_specs=tuple(P("pe") for _ in args),
-                         out_specs=({k: P("pe") for k in keys}, P("pe")))
+    m = jax.shard_map(fn, mesh=mesh1(),
+                      in_specs=tuple(P("pe") for _ in args),
+                      out_specs=({k: P("pe") for k in keys}, P("pe")),
+                      check_vma=False)
     fp = introspect.collective_footprint(m, *args)
     width = WireFormat.for_leaves(
         {**{k: payload[k].dtype for k in keys}, "_dest": jnp.int32}).width
@@ -356,9 +360,9 @@ def solve_footprint(n, mesh, cfg):
     specs = api_lib.build_specs(cfg, plan, n // plan.p, n, term_bound=8)
     fn = functools.partial(api_lib._solve_sharded, plan=plan, cfg=cfg,
                            specs=specs, m=n // plan.p)
-    m = compat.shard_map(fn, mesh, in_specs=(P("pe"), P("pe"), P()),
-                         out_specs=(P("pe"), P("pe"), P()),
-                         check_vma=False)
+    m = jax.shard_map(fn, mesh=mesh, in_specs=(P("pe"), P("pe"), P()),
+                      out_specs=(P("pe"), P("pe"), P()),
+                      check_vma=False)
     succ = jnp.arange(n, dtype=jnp.int32)
     rank = jnp.zeros(n, jnp.int32)
     return introspect.collective_footprint(m, succ, rank, jnp.int32(0))

@@ -9,13 +9,13 @@ import pytest
 from _graph_oracles import check_spanning_forest, union_find_labels
 from _tree_oracles import dfs_stats
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import graphalg, treealg
 from repro.core.listrank import ListRankConfig, instances
 
 
 def mesh1():
-    return compat.make_mesh((1,), ("pe",))
+    return make_mesh((1,), ("pe",))
 
 
 CFG = ListRankConfig(srs_rounds=1, local_contraction=False)

@@ -6,14 +6,14 @@ import numpy as np
 import pytest
 from _hypothesis_compat import HealthCheck, given, settings, st
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.listrank import (IndirectionSpec, ListRankConfig, analysis,
                                  instances, rank_list_seq,
                                  rank_list_with_stats)
 
 
 def mesh1():
-    return compat.make_mesh((1,), ("pe",))
+    return make_mesh((1,), ("pe",))
 
 
 def run_and_check(succ, rank, cfg, **kw):

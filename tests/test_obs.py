@@ -9,11 +9,12 @@ Marked ``obs`` (fast lane); the real-device mesh half runs in
 import functools
 import json
 
+import jax
 import numpy as np
 import pytest
 
 from _simshard_cases import AXES, SHAPE, case_record, golden_cases, load_golden
-from repro import compat, obs
+from repro import obs
 from repro.core import graphalg, treealg
 from repro.core.listrank import (FaultSpec, ListRankConfig,
                                  SolveExhausted, instances, introspect,
@@ -188,14 +189,14 @@ def test_mesh_program_counts_unaffected_by_active_tracer(p):
     n = 4 * p
     m = n // p
     cfg = ListRankConfig(srs_rounds=1, local_contraction=True)
-    am = compat.abstract_mesh((p,), ("pe",))
+    am = jax.sharding.AbstractMesh((p,), ("pe",))
     plan = MeshPlan.from_mesh(am, ("pe",))
     specs = api_lib.build_specs(cfg, plan, m, n, term_bound=m)
     spec = P(("pe",))
     fn = functools.partial(api_lib._solve_sharded, plan=plan, cfg=cfg,
                            specs=specs, m=m)
-    mapped = compat.shard_map(fn, mesh=am, in_specs=(spec, spec, P()),
-                              out_specs=(spec, spec, P()), check_vma=False)
+    mapped = jax.shard_map(fn, mesh=am, in_specs=(spec, spec, P()),
+                           out_specs=(spec, spec, P()), check_vma=False)
     args = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32), jnp.int32(0))
 
     baseline = introspect.collective_counts(mapped, *args)

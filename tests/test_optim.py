@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw, schedule
 from repro.runtime import compression
 
@@ -60,7 +60,7 @@ def test_master_weights_keep_bf16_params_training():
 def test_zero1_state_shardings_add_data_axis():
     import os
     from repro.models.params import spec
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {"w": spec((64, 32), ("embed", "mlp"))}
     sh = adamw.state_shardings(tree, mesh, adamw.AdamWConfig(), zero1=True)
     # with axis sizes 1 everything divides; the first unsharded dim of

@@ -4,16 +4,16 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.runtime import sharding as shlib
-from repro import compat
+from repro.launch.mesh import make_mesh
 
 
 def mesh44():
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def fake_mesh(shape, names):
     """Abstract mesh for resolution tests (no devices needed)."""
-    return compat.abstract_mesh(shape, names)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(names))
 
 
 def test_divisible_dims_shard():

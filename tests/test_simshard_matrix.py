@@ -14,11 +14,11 @@ capacity specs (the jit key) are instance-independent.
 The heavy cross-product tests carry the ``matrix`` marker (dedicated CI
 job; deselect with ``-m "not matrix"`` for the fast lane).
 """
+import jax
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.listrank import (ListRankConfig, instances, introspect,
                                  rank_list_seq, rank_list_with_stats,
                                  sim_mesh, tuner)
@@ -102,14 +102,14 @@ def test_solver_collective_counts_mesh_vs_simshard(p, packed):
     m = n // p
     cfg = BASE.with_(wire_packing=packed)
 
-    am = compat.abstract_mesh((p,), ("pe",))
+    am = jax.sharding.AbstractMesh((p,), ("pe",))
     plan_mesh = MeshPlan.from_mesh(am, ("pe",), None, wire_packing=packed)
     specs = api_lib.build_specs(cfg, plan_mesh, m, n, term_bound=m)
     spec = P(("pe",))
     fn = functools.partial(api_lib._solve_sharded, plan=plan_mesh, cfg=cfg,
                            specs=specs, m=m)
-    mapped = compat.shard_map(fn, mesh=am, in_specs=(spec, spec, P()),
-                              out_specs=(spec, spec, P()), check_vma=False)
+    mapped = jax.shard_map(fn, mesh=am, in_specs=(spec, spec, P()),
+                           out_specs=(spec, spec, P()), check_vma=False)
     args = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32), jnp.int32(0))
     counts_mesh = introspect.collective_counts(mapped, *args)
 

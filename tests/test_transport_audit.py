@@ -209,8 +209,8 @@ def test_mesh_backend_rejects_sim_mesh():
 def test_forced_simshard_on_real_mesh():
     """backend='simshard' with a real mesh: same axis names/sizes, no
     device placement — the escape hatch for large-p runs on any host."""
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     succ, rank = instances.gen_list(128, gamma=1.0, seed=40)
-    mesh = compat.make_mesh((1,), ("pe",))
+    mesh = make_mesh((1,), ("pe",))
     st = _solve_and_check(succ, rank, mesh, CFG.with_(backend="simshard"))
     assert st["attempts"] >= 1

@@ -13,7 +13,7 @@ import pytest
 from _tree_oracles import dfs_stats
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import treealg
 from repro.core.listrank import (ListRankConfig, instances, introspect,
                                  rank_list_seq)
@@ -24,7 +24,7 @@ from repro.core.treealg import batch as batch_lib
 
 
 def mesh1():
-    return compat.make_mesh((1,), ("pe",))
+    return make_mesh((1,), ("pe",))
 
 
 CFG = ListRankConfig(srs_rounds=1, local_contraction=False)
@@ -310,7 +310,7 @@ def solver_collective_counts(n, mesh, cfg):
     specs = api_lib.build_specs(cfg, plan, m, n, term_bound=8)
     fn = functools.partial(api_lib._solve_sharded, plan=plan, cfg=cfg,
                            specs=specs, m=m)
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=(P(pe_axes), P(pe_axes), P()),
         out_specs=(P(pe_axes), P(pe_axes), P()), check_vma=False)
     succ = jnp.arange(n, dtype=jnp.int32)

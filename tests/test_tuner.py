@@ -13,7 +13,7 @@ import math
 import numpy as np
 import pytest
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.listrank import (IndirectionSpec, ListRankConfig, analysis,
                                  instances, rank_list_seq,
                                  rank_list_with_stats, tuner)
@@ -22,7 +22,7 @@ from repro.core.listrank.exchange import MeshPlan
 
 
 def mesh1():
-    return compat.make_mesh((1,), ("pe",))
+    return make_mesh((1,), ("pe",))
 
 
 def plan_of(p=16, axes=2):
