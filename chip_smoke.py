@@ -1,7 +1,8 @@
 """Smoke test of the list-ranking solve on TPU chips.
 
-  python chip_smoke.py              # one chip
-  python chip_smoke.py --chips 4    # the 2x2 host: the mesh phases only
+  python chip_smoke.py                 # one chip
+  python chip_smoke.py --chips 4       # the 2x2 host: the mesh phases only
+  python chip_smoke.py --log2-n 16     # a smaller list, for a quick run
 
 Runs in one process and starts none. It prints the device first and
 exits non-zero, with no result line, where JAX finds no TPU: there is no
@@ -10,13 +11,14 @@ CPU fallback. It then ranks the paper's List(n, gamma=1) instance
 ``make_pe_mesh`` and checks every output exactly against the sequential
 oracle ``rank_list_seq``.
 
-One chip: n = 2^24 (see LOG2_N), whose fullest stage program must fit
-60% of the chip's HBM by the compiler's memory analysis. It solves with
-the default ``ListRankConfig()`` and with ``local_contraction=False``:
-at p=1 contraction alone absorbs the whole list, so the second run is
-the one in which the ruler chase, the recursion and the base case do
-the work. Then one small solve per Pallas kernel, with the kernel
-selected and present in the compiled stage programs.
+One chip: n = 2^LOG2_N (or 2^K with ``--log2-n K``), whose fullest
+stage program must fit 60% of the chip's HBM by the compiler's memory
+analysis. It solves with the default ``ListRankConfig()`` and with
+``local_contraction=False``: at p=1 contraction alone absorbs the whole
+list, so the second run is the one in which the ruler chase, the
+recursion and the base case do the work. Then one small solve per
+Pallas kernel, with the kernel selected and present in the compiled
+stage programs.
 
 ``--chips 4``: n = 4 x the one-chip size on the 2x2 ``("row", "col")``
 mesh, with direct and with grid indirection.
@@ -31,6 +33,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import sys
 import time
@@ -39,17 +42,22 @@ from concurrent.futures import ThreadPoolExecutor
 import jax
 import numpy as np
 
-#: one-chip size. 2^26 fits (its fullest stage program needs 9.11 GiB
-#: of a v5e's 15.75 GiB), but one default-config solve there took 277 s
-#: on the chip: both configs, the kernel phases and the compile do not
-#: fit the 1200 s this script may take (PERF.md).
+#: list elements per chip, log2, and why (PERF.md: at 2^26 the fullest
+#: stage program needs 9.11 GiB of a v5e's 15.75 GiB)
 LOG2_N = 24
+SIZE_REASON = ("2^26 fits a v5e's HBM, but its default-config solve "
+               "alone takes 277 s and the whole run must end within "
+               "1200 s")
 HBM_SHARE = 0.6               # of the device's memory, per program
 PACK_N = 1 << 18              # mailbox_pack phase size
 SEED = 0
-#: stage programs compiled at once; one 2^26 stage compile holds about
-#: 6 GB of host memory
-COMPILE_WORKERS = 4
+#: stage programs compiled at once. Each TPU compile runs on one host
+#: thread; a process compiling one peaked at 2.6 GB of host memory at
+#: 2^20 elements per chip and 3.7 GB at 2^22 (a v5e compile on a CPU
+#: host), so each gets 10 GiB.
+COMPILE_WORKERS = max(1, min(
+    (os.cpu_count() or 2) // 2,
+    os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE") // (10 << 30)))
 
 
 def fail(msg: str) -> None:
@@ -60,6 +68,9 @@ def fail(msg: str) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--log2-n", type=int, choices=range(12, 27),
+                    default=LOG2_N, metavar="K",
+                    help=f"list elements per chip, 2^K (default {LOG2_N})")
     args = ap.parse_args()
 
     devices = jax.devices()
@@ -76,13 +87,20 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_pe_mesh
 
-    print(f"compile cache: {enable_compile_cache()}", flush=True)
+    print(f"compile cache: {enable_compile_cache()}, at most "
+          f"{jax.config.jax_compilation_cache_max_size} bytes "
+          f"(-1: unbounded)", flush=True)
     mesh = make_pe_mesh(devices[:args.chips])
     hbm = dev.memory_stats()["bytes_limit"]
+    n = args.chips << args.log2_n
+    why = (SIZE_REASON if args.log2_n == LOG2_N
+           else f"--log2-n {args.log2_n}")
+    print(f"chosen n = {args.chips} x 2^{args.log2_n} = {n}: {why}",
+          flush=True)
     if args.chips == 1:
-        ok = one_chip(mesh, hbm, dev.device_kind)
+        ok = one_chip(n, mesh, hbm, dev.device_kind)
     else:
-        ok = four_chips(mesh, hbm, dev.device_kind)
+        ok = four_chips(n, mesh, hbm, dev.device_kind)
     if not ok:
         fail("a phase failed")
     print(json.dumps({"ok": True, "device": {
@@ -164,12 +182,11 @@ def oracle(n: int):
     return succ, rank, ref
 
 
-def one_chip(mesh, hbm: int, kind: str) -> bool:
+def one_chip(n: int, mesh, hbm: int, kind: str) -> bool:
     from repro.core.listrank import ListRankConfig
 
     default = ListRankConfig()
     chase = default.with_(local_contraction=False)
-    n = 1 << LOG2_N
     compile_and_fit(n, mesh, [(default, None), (chase, None)], hbm)
     succ, rank, ref = oracle(n)
     ok = solve_and_check("default config", succ, rank, ref, mesh, default,
@@ -213,12 +230,11 @@ def kernel_phases(mesh, kind: str) -> bool:
     return ok
 
 
-def four_chips(mesh, hbm: int, kind: str) -> bool:
+def four_chips(n: int, mesh, hbm: int, kind: str) -> bool:
     from repro.core.listrank import IndirectionSpec, ListRankConfig
 
     cfg = ListRankConfig()
     grid = IndirectionSpec.grid(("row", "col"))
-    n = mesh.size << LOG2_N
     compile_and_fit(n, mesh, [(cfg, None), (cfg, grid)], hbm)
     succ, rank, ref = oracle(n)
     ok = solve_and_check("2x2 direct", succ, rank, ref, mesh, cfg, None,

@@ -27,7 +27,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.core.listrank import local as local_lib
 from repro.core.listrank import store as store_lib
@@ -388,46 +388,32 @@ def lower_stages(n: int, mesh, pe_axes: Sequence[str] | None = None,
                  cfg: ListRankConfig | None = None,
                  indirection: IndirectionSpec | None = None,
                  term_bound: int = 1, weight_dtype=jnp.int32):
-    """Lower every stage program of a straight-through
-    :func:`rank_list_with_stats` solve of ``n`` elements from shapes
-    alone. Returns ``[(stage label, jax.stages.Lowered)]``.
+    """Lower every stage program of a :func:`rank_list_with_stats` solve
+    of ``n`` elements from shapes alone, as its first attempt runs them
+    (default capacity scales). Returns ``[(stage label, Lowered)]``.
     ``term_bound`` is the most list terminals on one PE (1 for a single
     list), which the front door counts from the instance.
 
     Nothing runs and no array is placed, so ``mesh`` may hold described
     devices (``jax.experimental.topologies``); ``.compile()`` on each
     result then says what the chip's compiler says, and its
-    ``memory_analysis()`` what the program needs on the device. Built
-    with the front door's own plan, specs and jitted stage runners, so
-    on a real mesh the executables are the ones the solve will use.
+    ``memory_analysis()`` what the program needs on the device. A solve
+    that escalates its capacities compiles larger programs for its
+    retries. ``cfg.capacity_estimation`` sizes mailboxes from the
+    instance itself, so it is refused here.
     """
     cfg = cfg or ListRankConfig()
+    if cfg.capacity_estimation:
+        raise ValueError("lower_stages cannot lower capacity_estimation: "
+                         "its mailbox sizes come from the instance")
     backend, mesh, pe_axes, plan, cfg, m = _resolve(n, mesh, pe_axes, cfg,
                                                     indirection)
     if backend != "mesh":
         raise ValueError("lower_stages needs a device mesh")
-    wdt = canonical_weight_dtype(weight_dtype)
-    specs = build_specs(cfg, plan, m, n, term_bound)
-    sched = resume_lib.schedule_for(cfg)
-    sharded = NamedSharding(mesh, P(pe_axes))
-
-    def placed(like):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=sharded), like)
-
-    succ = placed(jax.ShapeDtypeStruct((n,), jnp.int32))
-    rank = placed(jax.ShapeDtypeStruct((n,), wdt))
-    seed = jax.ShapeDtypeStruct((), jnp.int32)
-    out = []
-    for idx, stage in enumerate(sched):
-        state = (placed(resume_lib.boundary_template(
-            sched, idx, cfg, specs, m, plan.p, wdt)) if idx else None)
-        args = resume_lib._stage_args(stage, state, succ, rank, seed)
-        runner = resume_lib._jitted_stage(
-            mesh, plan, cfg, stage, resume_lib._stage_specs(stage, specs), m)
-        out.append((stage.label, runner.lower(*args)))
-    return out
+    return resume_lib.lower_staged(
+        mesh=mesh, plan=plan, cfg=cfg, m=m, n=n,
+        specs=build_specs(cfg, plan, m, n, term_bound),
+        weight_dtype=canonical_weight_dtype(weight_dtype))
 
 
 def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,

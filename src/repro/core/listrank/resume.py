@@ -659,10 +659,9 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
         try:
             if injector is not None:
                 injector.crash_before(stage.kind, stage.level)
-            runner = _jitted_stage(mesh, plan, cfg, stage,
-                                   _stage_specs(stage, specs), m)
-            args = _stage_args(stage, state, succ_d, rank_d,
-                               jnp.int32(seed))
+            runner, args = _stage_call(mesh, plan, cfg, stage, specs, m,
+                                       state, succ_d, rank_d,
+                                       jnp.int32(seed))
             t0 = time.time()
             out = runner(*args)
             jax.block_until_ready(jax.tree.leaves(out))
@@ -824,11 +823,41 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     return succ_f, rank_f, host_stats
 
 
-def _stage_args(stage: Stage, state, succ_d, rank_d, seed):
+def _stage_call(mesh, plan, cfg, stage: Stage, specs, m: int, state,
+                succ_d, rank_d, seed):
+    """(jitted runner, its arguments) for one stage of the schedule."""
+    runner = _jitted_stage(mesh, plan, cfg, stage,
+                           _stage_specs(stage, specs), m)
     if stage.kind == "prep":
-        return (succ_d, rank_d)
+        return runner, (succ_d, rank_d)
     if stage.kind == "descend":
-        return (state, seed)
+        return runner, (state, seed)
     if stage.kind == "post":
-        return (state, succ_d, rank_d)
-    return (state,)
+        return runner, (state, succ_d, rank_d)
+    return runner, (state,)
+
+
+def lower_staged(*, mesh, plan, cfg: ListRankConfig, m: int, n: int, specs,
+                 weight_dtype):
+    """``[(stage label, jax.stages.Lowered)]`` for every stage of the
+    schedule, lowered from abstract, block-sharded arguments: the
+    programs :func:`run_staged` runs for these ``specs``."""
+    sched = schedule_for(cfg)
+    sharded = NamedSharding(mesh, P(plan.pe_axes))
+
+    def placed(like):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharded), like)
+
+    succ = placed(jax.ShapeDtypeStruct((n,), jnp.int32))
+    rank = placed(jax.ShapeDtypeStruct((n,), weight_dtype))
+    seed = jax.ShapeDtypeStruct((), jnp.int32)
+    out = []
+    for idx, stage in enumerate(sched):
+        state = (placed(boundary_template(sched, idx, cfg, specs, m, plan.p,
+                                          weight_dtype)) if idx else None)
+        runner, args = _stage_call(mesh, plan, cfg, stage, specs, m, state,
+                                   succ, rank, seed)
+        out.append((stage.label, runner.lower(*args)))
+    return out

@@ -10,31 +10,7 @@ pairwise jumping).
 """
 from __future__ import annotations
 
-import os
-from concurrent.futures import ThreadPoolExecutor
-
 import numpy as np
-
-#: below this size one thread gathers faster than a pool.
-_THREADED_MIN = 1 << 20
-_WORKERS = os.cpu_count() or 1
-
-
-def _gather(pool, a: np.ndarray, idx: np.ndarray) -> np.ndarray:
-    """``a[idx]``, split over ``pool``'s threads when there is one
-    (``np.take`` releases the GIL; the gathers are memory-latency
-    bound, so threads overlap their misses)."""
-    if pool is None:
-        return a[idx]
-    out = np.empty(idx.shape, a.dtype)
-    cuts = np.linspace(0, idx.size, 4 * _WORKERS + 1).astype(int)
-
-    def part(k):
-        lo, hi = cuts[k], cuts[k + 1]
-        np.take(a, idx[lo:hi], out=out[lo:hi])
-
-    list(pool.map(part, range(cuts.size - 1)))
-    return out
 
 
 def rank_list_seq(succ: np.ndarray, rank: np.ndarray | None = None):
@@ -73,17 +49,11 @@ def rank_list_seq(succ: np.ndarray, rank: np.ndarray | None = None):
     # answer once 2^k exceeds every list length.
     s = succ.astype(np.int64)
     w = rank.copy()
-    pool = (ThreadPoolExecutor(_WORKERS)
-            if n >= _THREADED_MIN else None)
-    try:
-        for _ in range(max(int(n).bit_length(), 1) + 1):
-            if np.all(_gather(pool, is_term, s)):
-                break
-            w = w + _gather(pool, w, s)
-            s = _gather(pool, s, s)
-    finally:
-        if pool is not None:
-            pool.shutdown()
+    for _ in range(max(int(n).bit_length(), 1) + 1):
+        if np.all(is_term[s]):
+            break
+        w = w + w[s]
+        s = s[s]
     # A set of lists converges within ceil(log2 n)+1 jumps; anything
     # still short of a true terminal is on a cycle. (Cycles of even
     # length collapse to spurious fixed points under jumping, so the

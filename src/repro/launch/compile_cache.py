@@ -3,8 +3,14 @@
 A cold process compiles each of the solver's stage programs from
 scratch (seven for the default two SRS rounds), which on a TPU takes
 minutes at chip-filling sizes. The persistent cache keeps those
-executables across processes, keyed among other things on the cache
-directory, so the directory must not move between runs.
+executables across processes in one directory, which therefore must not
+move between runs.
+
+JAX evicts the least recently used entries once the directory outgrows
+``jax_compilation_cache_max_size`` (``JAX_COMPILATION_CACHE_MAX_SIZE``).
+The executables are large: the default config's stage programs at 2^24
+elements take 178 MiB on a v5e, so a bound below what one run compiles
+keeps little for the next.
 """
 from __future__ import annotations
 

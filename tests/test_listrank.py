@@ -162,3 +162,38 @@ def test_retry_on_tiny_capacity():
                                        max_retries=6)
     np.testing.assert_array_equal(np.asarray(s), s_ref)
     np.testing.assert_array_equal(np.asarray(r), r_ref)
+
+
+@pytest.mark.parametrize("cfg", [
+    ListRankConfig(),
+    ListRankConfig(local_contraction=False),
+    ListRankConfig(algorithm="doubling"),
+], ids=["default", "chase", "doubling"])
+def test_lower_stages_lowers_the_solve_programs(cfg, monkeypatch):
+    """lower_stages, from shapes alone, lowers the very programs (and
+    argument placements) that a first-attempt solve runs."""
+    from repro.core.listrank import api, resume
+
+    n = 1 << 10
+    succ, rank = instances.gen_list(n, gamma=1.0, seed=3)
+    ran = []
+    stage_call = resume._stage_call
+
+    def spy(*a):
+        runner, args = stage_call(*a)
+        ran.append(runner.lower(*args).as_text())
+        return runner, args
+
+    monkeypatch.setattr(resume, "_stage_call", spy)
+    run_and_check(succ, rank, cfg)
+    monkeypatch.undo()
+    lowered = api.lower_stages(n, mesh1(), cfg=cfg)
+    assert [lw.as_text() for _, lw in lowered] == ran
+
+
+def test_lower_stages_refuses_capacity_estimation():
+    from repro.core.listrank import api
+
+    with pytest.raises(ValueError, match="capacity_estimation"):
+        api.lower_stages(1 << 10, mesh1(),
+                         cfg=ListRankConfig(capacity_estimation=True))

@@ -58,8 +58,6 @@ def ref_rank_list_seq(succ, rank=None):
 @pytest.mark.parametrize("n,gamma,num_lists,seed", [
     (1, 0.0, 1, 0), (2, 1.0, 1, 1), (17, 0.5, 1, 2), (64, 1.0, 1, 3),
     (128, 0.3, 5, 4), (257, 1.0, 9, 5),
-    # threaded gathers (n >= sequential._THREADED_MIN)
-    (1 << 20, 1.0, 3, 6),
 ])
 def test_matches_walk_on_lists(n, gamma, num_lists, seed):
     succ, rank = instances.gen_list(n, gamma, seed=seed, num_lists=num_lists)
