@@ -1,0 +1,7 @@
+"""ascend_s (s, mean per solve): device-synced wall time of the SRS
+ascend levels (sum of the stage attempts' walls)."""
+from harness import stage_wall
+
+
+def read(run):
+    return stage_wall(run, lambda label: label.startswith("ascend@"))
