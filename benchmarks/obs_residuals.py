@@ -69,7 +69,7 @@ def main():
     headroom_report = []
     for fam, fam_kw in FAMILIES:
         succ, rank = make_instance(fam_kw, n)
-        tr = Tracer(meta={"name": f"obs_residuals/{fam}", "family": fam})
+        tr = Tracer()
         _, _, stats = rank_list_with_stats(succ, rank, mesh, cfg=cfg,
                                            seed=1, tracer=tr)
         rows = residual_rows(tr)

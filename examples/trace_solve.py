@@ -1,6 +1,6 @@
 """Flight recorder demo: one traced list-ranking solve, end to end.
 
-  PYTHONPATH=src python examples/trace_solve.py [trace.json]
+  PYTHONPATH=src python examples/trace_solve.py [profile_dir]
 
 Runs sparse-ruling-set on the simshard backend with the span tracer
 attached, then prints the three artifacts the observability layer
@@ -19,14 +19,19 @@ produces for every solve:
   4. the metrics registry — the solver's host stats ingested into one
      typed counter/gauge schema.
 
-and finally writes a Chrome-trace-event JSON (drop it on
-https://ui.perfetto.dev or chrome://tracing to browse the timeline).
+The solve runs under a ``jax.profiler`` trace written to
+``profile_dir`` (default ``trace_solve_profile``): the tracer puts
+every span on the trace's host plane as a ``repro:<cat>/<name>``
+annotation, beside the device's ops (open it in TensorBoard's profile
+plugin or https://ui.perfetto.dev).
 
 Tracing is host-side only: the traced program is byte-identical with
 the tracer on or off (asserted continuously by tests/test_obs.py).
 """
 import os
 import sys
+
+import jax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -41,16 +46,17 @@ from repro import obs  # noqa: E402
 
 def main():
     enable_compile_cache()
-    out_path = sys.argv[1] if len(sys.argv) > 1 else "trace.json"
+    out_dir = sys.argv[1] if len(sys.argv) > 1 else "trace_solve_profile"
     p, n = 8, 1 << 14
     succ, rank = instances.gen_list(n, gamma=1.0, seed=0)
     cfg = ListRankConfig(algorithm="srs", srs_rounds=2,
                          local_contraction=True, telemetry=True)
     mesh = sim_mesh(p)
 
-    tracer = obs.Tracer(meta={"name": "trace_solve", "n": n, "p": p})
-    succ_out, rank_out, stats = rank_list_with_stats(
-        succ, rank, mesh, cfg=cfg, seed=1, tracer=tracer)
+    tracer = obs.Tracer()
+    with jax.profiler.trace(out_dir):
+        succ_out, rank_out, stats = rank_list_with_stats(
+            succ, rank, mesh, cfg=cfg, seed=1, tracer=tracer)
 
     s_ref, r_ref = rank_list_seq(succ, rank)
     assert np.array_equal(np.asarray(succ_out), s_ref)
@@ -91,8 +97,14 @@ def main():
         snap.pop("help", None)
         print(f"  {metric.name:<40} {metric.kind:<9} {snap}")
 
-    obs.write_chrome_trace(tracer, out_path)
-    print(f"\nwrote {out_path} — open it at https://ui.perfetto.dev")
+    events = obs.profile_spans(out_dir)
+    starts = [t0 for _, t0, _ in events]
+    assert [name for name, _, _ in events] == [
+        f"{sp.cat}/{sp.name}" for sp in tracer.spans]
+    assert starts == sorted(starts)
+    print(f"\nwrote a profiler trace under {out_dir}: its host plane holds "
+          f"the {len(events)} spans as {obs.ANNOTATION_PREFIX}<cat>/<name> "
+          f"annotations")
 
 
 if __name__ == "__main__":

@@ -419,8 +419,6 @@ def _run_pipeline(edges, n_nodes, mesh, pe_axes, cfg, mode, seed,
                         "stages": [rec.to_json()],
                         "headroom": tele_lib.headroom_rows(
                             [rec], tuner.format_scales(scales))}
-                    tr.counter("telemetry/util_max", util["util_max"])
-                    tr.counter("telemetry/util_mean", util["util_mean"])
                 tr.end(att, wall_s=dt, outcome="committed", **util)
                 host = {k: np.asarray(jax.device_get(v))[:n_nodes]
                         for k, v in out.items()}

@@ -444,37 +444,46 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
     escalation path and the per-family fatal stats.
 
     ``tracer`` (a :class:`repro.obs.Tracer`) records the flight-recorder
-    span tree for the whole solve — the root ``solve`` span, the
-    capacity-estimation pre-pass, every stage execution/retry with
-    measured wall time and §2.6 predicted time, and checkpoint
+    span tree for the whole solve — the root ``solve`` span, the front
+    door's ``term_bound`` readback, placement and fingerprint, the
+    capacity-estimation pre-pass, every stage execution/retry with its
+    dispatch, device wait and counter readback, measured wall time and
+    §2.6 predicted time, the final stats readback, and checkpoint
     save/restore — and ingests the final ``host_stats`` into the
-    tracer's metrics registry. Host-side only; the traced programs are
-    bit-identical with tracing on or off.
+    tracer's metrics registry. The ``solve`` span ends with
+    ``host_syncs``, the blocking host<->device syncs the solve made.
+    Host-side only; the traced programs are bit-identical with tracing
+    on or off.
     """
     cfg = cfg or ListRankConfig()
     n = succ.shape[0]
     backend, mesh, pe_axes, plan, cfg, m = _resolve(n, mesh, pe_axes, cfg,
                                                     indirection)
     p = plan.p
-    s_host = None
-    if term_bound is None:
-        s_host = np.asarray(jax.device_get(succ))
-        owners = np.arange(n) // m
-        counts = np.bincount(owners[s_host == np.arange(n)], minlength=p)
-        term_bound = int(counts.max()) if counts.size else 0
-
     tr = trace_lib.ensure(tracer)
+    syncs_before = tr.host_syncs
     solve_span = tr.begin(
         "solve", cat="solve", n=n, p=p, backend=backend,
         algorithm=cfg.algorithm, machine=cfg.machine.name,
         indirection=[list(h) for h in plan.indirection.hops])
     try:
+        s_host = None
+        if term_bound is None:
+            with tr.span("term_bound", cat="frontdoor"):
+                s_host = np.asarray(jax.device_get(succ))
+                tr.host_sync()
+                owners = np.arange(n) // m
+                counts = np.bincount(owners[s_host == np.arange(n)],
+                                     minlength=p)
+                term_bound = int(counts.max()) if counts.size else 0
+
         estimate = None
         if cfg.capacity_estimation:
             # sampled-splitter pre-pass: size mailboxes for the measured
             # destination skew instead of the static slack guess.
             if s_host is None:
                 s_host = np.asarray(jax.device_get(succ))
+                tr.host_sync()
             with tr.span("estimate_capacities", cat="tuner") as est_span:
                 estimate = tuner.estimate_capacities(s_host, plan, m, cfg,
                                                      seed=seed)
@@ -482,15 +491,16 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
                                   hop_slack=list(estimate.hop_slack),
                                   max_frac=list(estimate.max_frac))
 
-        succ_d = transport_lib.put_sharded(mesh, pe_axes,
-                                           jnp.asarray(succ, jnp.int32))
-        # explicit weight-dtype canonicalization (chase_leaves): int
-        # weights stay integer end-to-end — ±1 tour weights round-trip
-        # exactly.
-        wdt = canonical_weight_dtype(
-            rank.dtype if hasattr(rank, "dtype") else np.asarray(rank).dtype)
-        rank_d = transport_lib.put_sharded(mesh, pe_axes,
-                                           jnp.asarray(rank, wdt))
+        with tr.span("place", cat="frontdoor"):
+            succ_d = transport_lib.put_sharded(mesh, pe_axes,
+                                               jnp.asarray(succ, jnp.int32))
+            # explicit weight-dtype canonicalization (chase_leaves): int
+            # weights stay integer end-to-end — ±1 tour weights
+            # round-trip exactly.
+            wdt = canonical_weight_dtype(rank.dtype if hasattr(rank, "dtype")
+                                         else np.asarray(rank).dtype)
+            rank_d = transport_lib.put_sharded(mesh, pe_axes,
+                                               jnp.asarray(rank, wdt))
 
         def build_level_specs(level_scales):
             return build_specs(cfg, plan, m, n, term_bound,
@@ -509,9 +519,11 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
             stage_counters=stage_counters, initial_scales=initial_scales,
             tracer=tracer)
     except BaseException as e:
-        tr.end(solve_span, outcome=type(e).__name__)
+        tr.end(solve_span, outcome=type(e).__name__,
+               host_syncs=tr.host_syncs - syncs_before)
         raise
-    tr.end(solve_span, outcome="ok", attempts=host_stats["attempts"])
+    tr.end(solve_span, outcome="ok", attempts=host_stats["attempts"],
+           host_syncs=tr.host_syncs - syncs_before)
     if "telemetry" in host_stats and estimate is not None:
         # back-test the sampled-splitter DKW margins against the skew
         # the solve actually observed (EXPERIMENTS.md §telemetry).

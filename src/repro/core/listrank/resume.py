@@ -455,15 +455,23 @@ def state_shardings(mesh, plan, like):
     return jax.tree.map(lambda _: sh, like)
 
 
+def _device_get(x, tr):
+    """``jax.device_get(x)``, counted on tracer ``tr`` as one blocking
+    host sync."""
+    tr.host_sync()
+    return jax.device_get(x)
+
+
 def solve_fingerprint(succ, rank, n: int, p: int, seed: int,
-                      cfg: ListRankConfig) -> str:
+                      cfg: ListRankConfig,
+                      tr=trace_lib.NULL_TRACER) -> str:
     """Identity of a solve for restore validation: instance bytes plus
     the backend-independent config. A checkpoint restores only into the
     same logical solve — on either backend (elastic), since backend and
     kernel toggles never change the computed bits."""
     h = hashlib.sha256()
-    h.update(np.asarray(jax.device_get(succ)).astype(np.int32).tobytes())
-    h.update(np.asarray(jax.device_get(rank)).tobytes())
+    h.update(np.asarray(_device_get(succ, tr)).astype(np.int32).tobytes())
+    h.update(np.asarray(_device_get(rank, tr)).tobytes())
     key = (n, p, int(seed),
            cfg.with_(backend="auto", use_pallas=False, use_pallas_pack=False))
     h.update(repr(key).encode())
@@ -474,15 +482,15 @@ def solve_fingerprint(succ, rank, n: int, p: int, seed: int,
 # host-side state validation + corruption
 # --------------------------------------------------------------------------
 
-def validate_state(state, n: int) -> None:
+def validate_state(state, n: int, tr=trace_lib.NULL_TRACER) -> None:
     """Host-side invariant check of a boundary state: every valid store
     slot must hold ids/succ inside [0, n). Catches the ``corrupt``
     injection's sentinel (and real bit-rot) before it is checkpointed
     or consumed by the next stage."""
     for j, st in enumerate(state["stores"]):
-        valid = np.asarray(jax.device_get(st.valid))
+        valid = np.asarray(_device_get(st.valid, tr))
         for plane in ("ids", "succ"):
-            v = np.asarray(jax.device_get(getattr(st, plane)))
+            v = np.asarray(_device_get(getattr(st, plane), tr))
             bad = valid & ((v < 0) | (v >= n))
             if bad.any():
                 k = int(np.argmax(bad))
@@ -491,11 +499,12 @@ def validate_state(state, n: int) -> None:
                     f"{int(v[k])} at slot {k} (n={n})")
 
 
-def _apply_corruption(state, spec: faults_lib.FaultSpec, mesh, plan, m: int):
+def _apply_corruption(state, spec: faults_lib.FaultSpec, mesh, plan, m: int,
+                      tr=trace_lib.NULL_TRACER):
     """Scribble the corrupt sentinel over PE ``spec.pe``'s slice of the
     top store's ``spec.plane`` — a lost/garbled mailbox plane."""
     st = state["stores"][0]
-    leaf = np.asarray(jax.device_get(getattr(st, spec.plane))).copy()
+    leaf = np.asarray(_device_get(getattr(st, spec.plane), tr)).copy()
     pe = spec.pe % max(plan.p, 1)
     leaf[pe * m:(pe + 1) * m] = faults_lib.CORRUPT_SENTINEL
     leaf_d = transport_lib.put_sharded(mesh, plan.pe_axes, jnp.asarray(leaf))
@@ -505,10 +514,10 @@ def _apply_corruption(state, spec: faults_lib.FaultSpec, mesh, plan, m: int):
     return out
 
 
-def _fatal_totals(stats) -> dict:
+def _fatal_totals(stats, tr=trace_lib.NULL_TRACER) -> dict:
     """Global fatal-stat totals from a boundary state's per-PE stats (or
     post's already-reduced dict)."""
-    return {k: int(np.sum(np.asarray(jax.device_get(stats[k]))))
+    return {k: int(np.sum(np.asarray(_device_get(stats[k], tr))))
             for k in FATAL_KEYS}
 
 
@@ -532,9 +541,15 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     ``stage_counters`` records each executed stage's traced collective
     counts in ``host_stats["stage_collectives"]``; ``tracer`` (a
     :class:`repro.obs.Tracer`) records the flight-recorder span tree —
-    one ``stage`` span per schedule slot with one nested
-    ``stage-attempt`` span per execution, each annotated with the
-    §2.6 predicted time and the stage's static collective footprint.
+    the ``frontdoor/fingerprint`` span, one ``stage`` span per schedule
+    slot with one nested ``stage-attempt`` span per execution (each with
+    ``driver`` spans ``dispatch``: runner lookup and enqueue, ``wait``:
+    the device sync, ``readback``: the fatal counters; annotated with
+    the §2.6 predicted time and the stage's static collective
+    footprint), and a last ``driver/readback`` of the stats — and counts
+    each ``device_get`` and ``block_until_ready`` of the driver on it
+    (``Tracer.host_sync``). ``wall_s`` of an attempt is read on
+    ``perf_counter``: enqueue plus device wait.
     The tracer is host-side only: it never enters a jit key or a traced
     body, so the executed programs are bit-identical with it on or off.
     """
@@ -561,7 +576,8 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     if supervisor is not None:
         supervisor.tracer = tr
 
-    fp = solve_fingerprint(succ_d, rank_d, n, p, seed, cfg)
+    with tr.span("fingerprint", cat="frontdoor"):
+        fp = solve_fingerprint(succ_d, rank_d, n, p, seed, cfg, tr)
 
     # one stage span per schedule slot stays open across its overflow
     # retries (attempts nest under it); footprints are static per jitted
@@ -626,7 +642,7 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                                  jnp.dtype(meta["weight_dtype"]))
         state, _ = supervisor.restore(like, state_shardings(mesh, plan, like))
         supervisor.stats["resumed_from"] = int(meta["idx"])
-        return state, int(meta["idx"]), _fatal_totals(state["stats"])
+        return state, int(meta["idx"]), _fatal_totals(state["stats"], tr)
 
     state, idx = None, 0
     prev_fatal = {k: 0 for k in FATAL_KEYS}
@@ -659,13 +675,16 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
         try:
             if injector is not None:
                 injector.crash_before(stage.kind, stage.level)
-            runner, args = _stage_call(mesh, plan, cfg, stage, specs, m,
-                                       state, succ_d, rank_d,
-                                       jnp.int32(seed))
-            t0 = time.time()
-            out = runner(*args)
-            jax.block_until_ready(jax.tree.leaves(out))
-            dt = time.time() - t0
+            with tr.span("dispatch", cat="driver"):
+                runner, args = _stage_call(mesh, plan, cfg, stage, specs, m,
+                                           state, succ_d, rank_d,
+                                           jnp.int32(seed))
+                t0 = time.perf_counter()
+                out = runner(*args)
+            with tr.span("wait", cat="driver"):
+                jax.block_until_ready(jax.tree.leaves(out))
+                tr.host_sync()
+                dt = time.perf_counter() - t0
             if stage.kind == "post":
                 out_state, fatal_src = state, out[2]
             else:
@@ -678,8 +697,8 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                                stage=stage.label, plane=cspec.plane)
                     if stage.kind != "post":
                         out_state = out = _apply_corruption(
-                            out, cspec, mesh, plan, m)
-                validate_state(out_state, n)
+                            out, cspec, mesh, plan, m, tr)
+                validate_state(out_state, n, tr)
         except (faults_lib.InjectedFault, faults_lib.CorruptedState) as e:
             crashes += 1
             if isinstance(e, faults_lib.InjectedFault):
@@ -704,7 +723,8 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
 
         if tr.enabled:
             att.annotate(**stage_prediction(runner, args))
-        fatal = _fatal_totals(fatal_src)
+        with tr.span("readback", cat="driver"):
+            fatal = _fatal_totals(fatal_src, tr)
         delta = {k: fatal[k] - prev_fatal[k] for k in FATAL_KEYS}
         fam = (injector.overflow_after(stage.kind, stage.level)
                if injector is not None else None)
@@ -751,8 +771,9 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
             # not — and must not — carry it).
             tele_pe = (out[3] if stage.kind == "post"
                        else out_state.pop("_telemetry"))
-            agg = tele_lib.aggregate(jax.device_get(tele_pe))
+            agg = tele_lib.aggregate(_device_get(tele_pe, tr))
             util = tele_lib.utilization(agg)
+            util["queue_hwm"] = float(agg.get("queue_hwm", 0))
             spec_u = _stage_specs(stage, specs)[0]
             tele_records.append(tele_lib.StageRecord(
                 label=stage.label, kind=stage.kind, level=stage.level,
@@ -763,10 +784,6 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                               spec_u.gather_req_cap,
                               spec_u.gather_resp_cap))},
                 queue_cap=spec_u.queue_cap, tele=agg))
-            tr.counter("telemetry/util_max", util["util_max"])
-            tr.counter("telemetry/util_mean", util["util_mean"])
-            tr.counter("telemetry/queue_hwm",
-                       float(agg.get("queue_hwm", 0)))
         tr.end(att, wall_s=dt, outcome="committed", **util)
         close_stage_span()
         if tr.enabled:
@@ -801,7 +818,9 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     else:  # pragma: no cover - schedule always ends with post
         raise AssertionError("schedule ended without a post stage")
 
-    host_stats = {k: int(jax.device_get(v)) for k, v in dev_stats.items()}
+    with tr.span("readback", cat="driver"):
+        host_stats = {k: int(_device_get(v, tr))
+                      for k, v in dev_stats.items()}
     host_stats["attempts"] = attempts
     host_stats["scales_log"] = ";".join(scales_log)
     host_stats["stage_log"] = tuple(stage_log)

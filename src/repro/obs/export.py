@@ -1,89 +1,13 @@
-"""Exporters: Chrome-trace JSON (Perfetto-loadable) and the residual
-table.
+"""The residual table: the §2.6 model against the measured spans.
 
-``chrome_trace`` serializes a :class:`~repro.obs.trace.Tracer` into the
-Chrome trace-event format (the JSON array-of-events "traceEvents" form
-that chrome://tracing and https://ui.perfetto.dev load directly):
-
-- every span -> one complete ("ph": "X") event, microsecond ``ts``
-  relative to the tracer epoch, ``dur`` from the device-sync-bounded
-  wall time, ``cat`` from the span taxonomy (DESIGN.md §12), and the
-  span's annotations (level, attempt, scales, collective footprint,
-  predicted time) under ``args``;
-- every instant (fault injections, preemptions, escalations) -> an
-  "i" event with thread scope — the recovery timeline;
-- tracer ``meta`` -> process_name / metadata events.
-
-``residual_rows`` / ``format_residual_table`` turn the same spans into
-the §2.6 model-vs-measured artifact: one row per stage attempt with
-measured wall seconds, predicted seconds, the residual, and the
-counted collective footprint.
+``residual_rows`` / ``format_residual_table`` turn a
+:class:`~repro.obs.trace.Tracer`'s spans into the §2.6
+model-vs-measured artifact: one row per stage attempt with measured
+wall seconds, predicted seconds, the residual, and the counted
+collective footprint. (The spans' timeline is the JAX profiler's own
+trace: a recording tracer writes each span there as a host annotation.)
 """
 from __future__ import annotations
-
-import json
-
-from repro.obs.metrics import json_safe
-
-_US = 1e6
-
-
-def chrome_trace(tracer, pid: int = 0) -> dict:
-    """The trace as a Chrome trace-event dict (``json.dump``-ready).
-
-    Tolerates a :class:`~repro.obs.trace.NullTracer` (or any tracer
-    missing attributes): the result is a minimal but valid trace —
-    exporters must never take down a solve."""
-    meta = getattr(tracer, "meta", None) or {}
-    spans = getattr(tracer, "spans", ()) or ()
-    instants = getattr(tracer, "instants", ()) or ()
-    events = [{
-        "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
-        "args": {"name": meta.get("name", "repro-solve")},
-    }]
-    if meta:
-        events.append({"ph": "M", "name": "process_labels", "pid": pid,
-                       "tid": 0,
-                       "args": {"labels": json.dumps(json_safe(meta))}})
-    end_fallback = max((s.t1 for s in spans if s.t1 is not None),
-                       default=0.0)
-    for s in spans:
-        t1 = s.t1 if s.t1 is not None else end_fallback
-        events.append({
-            "ph": "X", "name": s.name, "cat": s.cat, "pid": pid,
-            "tid": s.depth,
-            "ts": round(s.t0 * _US, 3),
-            "dur": round(max(t1 - s.t0, 0.0) * _US, 3),
-            "args": json_safe(s.args),
-        })
-    for s in instants:
-        events.append({
-            "ph": "i", "name": s.name, "cat": s.cat, "pid": pid,
-            "tid": s.depth, "s": "t",
-            "ts": round(s.t0 * _US, 3),
-            "args": json_safe(s.args),
-        })
-    # counter tracks (telemetry utilization / queue HWM series); sorted
-    # by time so each track's series is monotone in ts regardless of
-    # which driver emitted the sample.
-    for name, t, value in sorted(getattr(tracer, "counters", ()) or (),
-                                 key=lambda c: c[1]):
-        events.append({
-            "ph": "C", "name": name, "cat": "telemetry", "pid": pid,
-            "tid": 0,
-            "ts": round(t * _US, 3),
-            "args": {"value": float(value)},
-        })
-    return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"epoch_unix": getattr(tracer, "epoch_unix", 0.0),
-                          **json_safe(meta)}}
-
-
-def write_chrome_trace(tracer, path: str, pid: int = 0) -> str:
-    """Write the Chrome trace JSON to ``path``; returns the path."""
-    with open(path, "w") as f:
-        json.dump(chrome_trace(tracer, pid=pid), f, indent=1)
-    return path
 
 
 # --------------------------------------------------------------------------
@@ -164,5 +88,4 @@ def residual_summary(rows: list[dict]) -> dict:
     }
 
 
-__all__ = ["chrome_trace", "write_chrome_trace", "residual_rows",
-           "format_residual_table", "residual_summary"]
+__all__ = ["residual_rows", "format_residual_table", "residual_summary"]

@@ -10,9 +10,9 @@ strings sourced from the owning modules (``srs.STAT_HELP``,
 ``graphalg.cc.GRAPH_STAT_HELP``).
 
 Also home to :func:`json_safe` — the canonical "make this stats value
-JSON-serializable" conversion used by the bench workers and the
-Chrome-trace exporter (host_stats now carries tuples and nested dicts,
-which ``int()``-casting bench code used to choke on).
+JSON-serializable" conversion used by the bench workers (host_stats
+now carries tuples and nested dicts, which ``int()``-casting bench code
+used to choke on).
 """
 from __future__ import annotations
 

@@ -15,16 +15,30 @@ collectives — a solve with tracing on reproduces the tracer-off bytes,
 counters, and jaxpr collective counts exactly (pinned in
 ``tests/test_obs.py``).
 
+A recording tracer also writes every span into the JAX profiler as a
+host annotation named ``<ANNOTATION_PREFIX><cat>/<name>``: ``begin``
+enters a :class:`jax.profiler.TraceAnnotation` and ``end`` exits it
+(and those of the forgotten children it closes, innermost first). In a
+``jax.profiler`` trace the spans then sit on the host plane, on the
+same clock as the device's ops. The tracer also counts the solve
+path's blocking host<->device syncs (:meth:`Tracer.host_sync`).
+
 When tracing is off, every instrumentation site goes through
 :data:`NULL_TRACER`, whose ``span``/``begin`` return one shared
-:data:`NULL_SPAN` singleton — no Span objects are allocated, no clock
-is read (also pinned by test).
+:data:`NULL_SPAN` singleton — no Span objects or annotations are
+allocated, no clock is read (also pinned by test).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Iterator
+from pathlib import Path
+from typing import Iterator
+
+from jax.profiler import TraceAnnotation
+
+#: prefix of every span's host annotation in the JAX profiler's trace
+ANNOTATION_PREFIX = "repro:"
 
 
 @dataclasses.dataclass
@@ -55,13 +69,16 @@ class Span:
 class _SpanHandle:
     """A live span bound to its tracer — usable as a context manager
     (``with tracer.span(...) as sp``) or via explicit
-    ``tracer.end(handle)``."""
+    ``tracer.end(handle)``. Holds the span's entered profiler
+    annotation until the span ends."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span,
+                 annotation: TraceAnnotation):
         self._tracer = tracer
         self.span = span
+        self._annotation = annotation
 
     def annotate(self, **kw) -> "_SpanHandle":
         self.span.args.update(kw)
@@ -103,7 +120,7 @@ class NullTracer:
 
     enabled = False
     spans: tuple = ()
-    counters: tuple = ()
+    host_syncs = 0
     metrics = None
 
     def span(self, name: str, cat: str = "host", **args):
@@ -118,7 +135,7 @@ class NullTracer:
     def instant(self, name: str, cat: str = "host", **args) -> None:
         pass
 
-    def counter(self, name: str, value, t: float | None = None) -> None:
+    def host_sync(self) -> None:
         pass
 
 
@@ -134,7 +151,6 @@ def ensure(tracer) -> "Tracer | NullTracer":
 class Tracer:
     """The recording tracer.
 
-    ``meta`` rides into the Chrome-trace export as process metadata;
     ``metrics`` is an optional
     :class:`~repro.obs.metrics.MetricsRegistry` the instrumented
     drivers feed (one is created lazily on first use if not supplied).
@@ -142,18 +158,13 @@ class Tracer:
 
     enabled = True
 
-    def __init__(self, meta: dict | None = None, metrics=None,
-                 clock=time.perf_counter):
-        self.meta = dict(meta or {})
+    def __init__(self, metrics=None, clock=time.perf_counter):
         self._clock = clock
         self.epoch = clock()
-        #: wall-clock time of the epoch (for trend records / trace meta)
-        self.epoch_unix = time.time()
         self.spans: list[Span] = []
         self.instants: list[Span] = []
-        #: counter-track samples: (name, t_seconds, float value) — the
-        #: telemetry plane's utilization series (Perfetto "C" events).
-        self.counters: list[tuple[str, float, float]] = []
+        #: blocking host<->device syncs counted so far (``host_sync``)
+        self.host_syncs = 0
         self._stack: list[_SpanHandle] = []
         self._metrics = metrics
 
@@ -175,7 +186,9 @@ class Tracer:
                     parent=parent, depth=len(self._stack), t0=self.now(),
                     args=dict(args))
         self.spans.append(span)
-        handle = _SpanHandle(self, span)
+        annotation = TraceAnnotation(f"{ANNOTATION_PREFIX}{cat}/{name}")
+        annotation.__enter__()
+        handle = _SpanHandle(self, span, annotation)
         self._stack.append(handle)
         return handle
 
@@ -183,15 +196,16 @@ class Tracer:
         if isinstance(handle, _NullSpan):  # tolerate mixed call sites
             return
         handle.span.args.update(args)
-        # close any forgotten children so the tree stays well-formed
-        while self._stack:
+        if handle.span.t1 is not None:
+            return  # already ended
+        # close any forgotten children, innermost first, so the span
+        # tree and the profiler's annotations stay well nested
+        while True:
             top = self._stack.pop()
-            if top.span.t1 is None:
-                top.span.t1 = self.now()
+            top._annotation.__exit__(None, None, None)
+            top.span.t1 = self.now()
             if top is handle:
                 return
-        if handle.span.t1 is None:  # already off-stack (double end)
-            handle.span.t1 = self.now()
 
     def span(self, name: str, cat: str = "host", **args) -> _SpanHandle:
         """``with tracer.span("base@2", cat="stage") as sp: ...``"""
@@ -205,12 +219,10 @@ class Tracer:
                                   parent=parent, depth=len(self._stack),
                                   t0=t, t1=t, args=dict(args)))
 
-    def counter(self, name: str, value, t: float | None = None) -> None:
-        """Sample a counter track (mailbox utilization, queue HWM) at
-        ``t`` (tracer-relative seconds; now() when omitted). Exported
-        as Chrome "C" events — one track per name."""
-        self.counters.append((name, self.now() if t is None else float(t),
-                              float(value)))
+    def host_sync(self) -> None:
+        """Count one blocking host<->device sync (a ``device_get`` or
+        ``block_until_ready``)."""
+        self.host_syncs += 1
 
     # ------------------------------------------------------------ queries
     def find(self, cat: str | None = None,
@@ -246,8 +258,26 @@ def maybe(tracer, cond: bool) -> "Tracer | NullTracer":
     return tracer if cond else NULL_TRACER
 
 
+def profile_spans(logdir) -> list[tuple[str, float, float]]:
+    """The spans a :class:`Tracer` wrote into the newest
+    ``jax.profiler`` trace under ``logdir``: ``(cat/name, start s,
+    end s)`` of each prefixed host annotation, by start. Times are on
+    the profiler's clock, which the trace's device ops share."""
+    from jax.profiler import ProfileData
+
+    files = sorted(Path(logdir).glob("plugins/profile/*/*.xplane.pb"),
+                   key=lambda f: f.stat().st_mtime)
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {logdir}")
+    pd = ProfileData.from_file(str(files[-1]))
+    return sorted(((ev.name[len(ANNOTATION_PREFIX):], ev.start_ns * 1e-9,
+                    (ev.start_ns + ev.duration_ns) * 1e-9)
+                   for plane in pd.planes if plane.name == "/host:CPU"
+                   for line in plane.lines for ev in line.events
+                   if ev.name.startswith(ANNOTATION_PREFIX)),
+                  key=lambda e: (e[1], -e[2]))
+
+
 __all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN",
-           "ensure", "maybe", "span_tree_lines"]
-
-
-_ = Any  # typing import kept for annotations above
+           "ANNOTATION_PREFIX", "ensure", "maybe", "profile_spans",
+           "span_tree_lines"]

@@ -409,9 +409,10 @@ def suite_obs():
     """Flight recorder on the REAL mesh backend: a traced full solve
     must cover every scheduled stage with measured + predicted times,
     reproduce the committed golden bytes exactly (no-perturbation), and
-    export a loadable Chrome trace. Writes the trace artifact to
-    $OBS_TRACE_OUT when set (the CI simshard-matrix job uploads it)."""
-    import json
+    put one host annotation per span into the ``jax.profiler`` trace
+    around it. Writes the profile under $OBS_TRACE_OUT when set (the CI
+    simshard-matrix job uploads it), else in a temporary directory."""
+    import tempfile
     from _simshard_cases import (AXES as G_AXES, SHAPE as G_SHAPE,
                                  case_record, golden_cases, load_golden)
     from repro import obs
@@ -421,8 +422,11 @@ def suite_obs():
     s, r, cfg = next((s, r, c) for nm, s, r, c in golden_cases()
                      if nm == name)
     mesh = make_mesh(G_SHAPE, G_AXES)
-    tr = obs.Tracer(meta={"name": f"smoke-obs/{name}", "backend": "mesh"})
-    sf, rf, stats = rank_list_with_stats(s, r, mesh, cfg=cfg, tracer=tr)
+    tr = obs.Tracer()
+    tmp = tempfile.TemporaryDirectory()
+    logdir = os.environ.get("OBS_TRACE_OUT", "") or tmp.name
+    with jax.profiler.trace(logdir):
+        sf, rf, stats = rank_list_with_stats(s, r, mesh, cfg=cfg, tracer=tr)
     check("mesh golden bytes identical with tracing on",
           case_record(sf, rf, stats) == load_golden(name))
 
@@ -440,18 +444,15 @@ def suite_obs():
           {row["stage"] for row in rows} == set(labels)
           and all(row["measured_s"] >= 0 for row in rows))
 
-    out = os.environ.get("OBS_TRACE_OUT", "")
-    path = out or os.path.join(os.path.dirname(__file__), "..",
-                               "benchmarks", "results",
-                               "mesh_solve_trace.json")
-    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    obs.write_chrome_trace(tr, path)
-    doc = json.loads(open(path).read())
-    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    check("chrome trace round-trips with monotone timestamps",
-          len(xs) == len(tr.spans)
-          and [e["ts"] for e in xs] == sorted(e["ts"] for e in xs))
-    print(f"wrote {path}")
+    events = obs.profile_spans(logdir)
+    starts = [t0 for _, t0, _ in events]
+    check("profiler host plane holds one event per span, starts in order",
+          [e[0] for e in events] == [f"{sp.cat}/{sp.name}"
+                                     for sp in tr.spans]
+          and starts == sorted(starts))
+    if logdir != tmp.name:
+        print(f"wrote a profile under {logdir}")
+    tmp.cleanup()
 
 
 SUITES = {"exchange": suite_exchange, "listrank": suite_listrank,

@@ -1,13 +1,15 @@
 """Flight-recorder tests (``repro.obs``): span-tree shape, the
 no-perturbation pins (tracer on == tracer off, byte for byte and
 collective count for collective count), the metrics registry schema,
-the Chrome-trace exporter, and the zero-cost disabled path.
+the spans' host annotations in the ``jax.profiler`` trace, the
+host-sync counter, and the zero-cost disabled path.
 
 Marked ``obs`` (fast lane); the real-device mesh half runs in
 ``tests/_subprocess_smoke.py`` suite ``obs``.
 """
 import functools
 import json
+import tempfile
 
 import jax
 import numpy as np
@@ -210,12 +212,17 @@ def test_mesh_program_counts_unaffected_by_active_tracer(p):
 
 def test_disabled_tracer_allocates_no_spans(monkeypatch):
     """With tracing off every instrumentation site goes through
-    NULL_TRACER; no Span object may be constructed anywhere in the
-    solve/graphalg/treealg paths (near-zero disabled overhead)."""
+    NULL_TRACER; no Span object and no profiler annotation may be
+    constructed anywhere in the solve/graphalg/treealg paths (near-zero
+    disabled overhead)."""
     def boom(*a, **kw):
         raise AssertionError("Span allocated with tracing disabled")
 
+    def no_annotation(*a, **kw):
+        raise AssertionError("TraceAnnotation made with tracing disabled")
+
     monkeypatch.setattr(trace_lib, "Span", boom)
+    monkeypatch.setattr(trace_lib, "TraceAnnotation", no_annotation)
     s, r, cfg = small_case()
     sf, rf, stats = rank_list_with_stats(s, r, mesh8(), cfg=cfg, seed=1)
     assert np.array_equal(np.asarray(rf), rank_list_seq(s, r)[1])
@@ -300,86 +307,226 @@ def test_json_safe_stats_handles_solver_stats():
 
 
 # --------------------------------------------------------------------------
-# exporter
+# the spans in the jax.profiler trace
 # --------------------------------------------------------------------------
 
-def test_chrome_trace_roundtrip(tmp_path):
+def profiled(fn):
+    """Run ``fn()`` under a ``jax.profiler`` trace; returns the spans a
+    tracer wrote there: [(cat/name, start s, end s)] by start."""
+    with tempfile.TemporaryDirectory() as logdir:
+        with jax.profiler.trace(logdir):
+            fn()
+        return obs.profile_spans(logdir)
+
+
+def span_label(sp):
+    return f"{sp.cat}/{sp.name}"
+
+
+def assert_nested_as_tree(tr, events):
+    """One profiler event per recorded span, in begin order, each inside
+    the event of its parent span."""
+    assert [e[0] for e in events] == [span_label(sp) for sp in tr.spans]
+    for sp, (_, t0, t1) in zip(tr.spans, events):
+        assert t0 <= t1
+        if sp.parent >= 0:
+            _, p0, p1 = events[sp.parent]
+            assert p0 <= t0 and t1 <= p1
+
+
+def test_chrome_trace_roundtrip():
+    """The solve's timeline is the profiler's own trace: a traced solve
+    through an injected overflow and its retry writes one prefixed host
+    event per span, with starts in begin order, nested as the span
+    tree; the fault instants stay in the tracer's record, inside the
+    attempt that raised them."""
     s, r, cfg = CASES["list-g1-s1"]
-    tr = obs.Tracer(meta={"name": "roundtrip"})
-    rank_list_with_stats(
+    tr = obs.Tracer()
+    events = profiled(lambda: rank_list_with_stats(
         s, r, mesh8(), cfg=cfg, tracer=tr,
         inject=FaultSpec("overflow", stage="descend", level=0,
-                         family="chase"))
-    path = tmp_path / "trace.json"
-    obs.write_chrome_trace(tr, path)
-    doc = json.loads(path.read_text())
-    evs = doc["traceEvents"]
-    assert any(e["ph"] == "M" and e["name"] == "process_name" for e in evs)
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert len(xs) == len(tr.spans)
-    for e in xs:
-        assert e["dur"] >= 0 and e["ts"] >= 0
-    # spans export in begin order: timestamps are monotone nondecreasing
-    ts = [e["ts"] for e in xs]
-    assert ts == sorted(ts)
-    # the injected fault shows up as a thread-scoped instant
-    instants = [e for e in evs if e["ph"] == "i"]
-    assert any(e["name"] == "overflow:chase:descend@0" for e in instants)
-    assert all(e["s"] == "t" for e in instants)
+                         family="chase")))
+    assert len(events) == len(tr.spans)
+    starts = [t0 for _, t0, _ in events]
+    assert starts == sorted(starts)
+    assert_nested_as_tree(tr, events)
+    (over,) = [i for i in tr.instants if i.name == "overflow:chase:descend@0"]
+    (first,) = tr.find(cat="stage-attempt", name="descend@0#1")
+    assert first.t0 <= over.t0 <= first.t1
 
 
-def test_chrome_trace_null_tracer_and_empty_tree(tmp_path):
-    """Exporter edge cases: the NullTracer, a tracer with no spans at
-    all, and spans without counter samples all export valid
-    Perfetto-loadable JSON (round-trips through json)."""
-    for tracer in (trace_lib.NULL_TRACER, obs.Tracer()):
-        doc = obs.chrome_trace(tracer)
-        blob = json.dumps(doc)
-        back = json.loads(blob)
-        assert isinstance(back["traceEvents"], list)
-        assert back["traceEvents"][0]["ph"] == "M"
-        assert back["displayTimeUnit"] == "ms"
-        assert not [e for e in back["traceEvents"] if e["ph"] == "C"]
-    # spans but no counters: X events export, no C events
-    tr = obs.Tracer(meta={"name": "edge"})
-    with tr.span("solo", cat="stage"):
-        pass
-    path = tmp_path / "edge.json"
-    obs.write_chrome_trace(tr, path)
-    doc = json.loads(path.read_text())
-    phs = [e["ph"] for e in doc["traceEvents"]]
-    assert "X" in phs and "C" not in phs
+def test_chrome_trace_null_tracer_and_empty_tree():
+    """Edge cases of the profiler annotations: a solve through the
+    NullTracer writes no prefixed event, a tracer with no span writes
+    none, and a lone span writes exactly one."""
+    s, r, cfg = small_case()
+    assert profiled(lambda: rank_list_with_stats(
+        s, r, mesh8(), cfg=cfg, seed=1)) == []
+    assert profiled(obs.Tracer) == []
+    tr = obs.Tracer()
+
+    def one_span():
+        with tr.span("solo", cat="stage"):
+            pass
+
+    (event,) = profiled(one_span)
+    assert event[0] == "stage/solo" and event[1] <= event[2]
 
 
 def test_counter_tracks_interleave_with_fault_instants():
-    """Counter samples and fault instants share the timeline: both
-    export, counter events are time-sorted, and their timestamps land
-    inside the span that emitted them."""
+    """Utilization values ride as args of the attempt spans, and fault
+    instants share the tracer's clock with them: each instant lies
+    inside the span open when it was raised, between the attempts it
+    separates, and the attempts' profiler events nest in the solve's."""
     tr = obs.Tracer()
-    with tr.span("solve", cat="solve"):
-        tr.instant("fault:injected", cat="fault")
-        tr.counter("telemetry/util_max", 0.25)
-        tr.instant("fault:recovered", cat="fault")
-        tr.counter("telemetry/util_max", 0.75)
-        tr.counter("telemetry/queue_hwm", 12.0)
-    doc = obs.chrome_trace(tr)
-    evs = doc["traceEvents"]
-    cs = [e for e in evs if e["ph"] == "C"]
-    instants = [e for e in evs if e["ph"] == "i"]
-    assert len(cs) == 3 and len(instants) == 2
-    assert [e["ts"] for e in cs] == sorted(e["ts"] for e in cs)
-    assert {e["name"] for e in cs} == {"telemetry/util_max",
-                                       "telemetry/queue_hwm"}
-    assert all(e["args"]["value"] >= 0 for e in cs)
-    (solve,) = [e for e in evs if e["ph"] == "X"]
-    for e in cs + instants:
-        assert solve["ts"] <= e["ts"] <= solve["ts"] + solve["dur"]
-    json.dumps(doc)
+
+    def record():
+        with tr.span("solve", cat="solve"):
+            tr.instant("fault:injected", cat="fault")
+            with tr.span("descend@0#1", cat="stage-attempt") as a:
+                a.annotate(util_max=0.25, util_mean=0.125)
+            tr.instant("fault:recovered", cat="fault")
+            with tr.span("descend@0#2", cat="stage-attempt") as b:
+                b.annotate(util_max=0.75, util_mean=0.5, queue_hwm=12.0)
+
+    events = profiled(record)
+    assert_nested_as_tree(tr, events)
+    solve, first, second = tr.spans
+    injected, recovered = tr.instants
+    assert solve.t0 <= injected.t0 <= first.t0
+    assert first.t1 <= recovered.t0 <= second.t0 <= second.t1 <= solve.t1
+    assert [sp.args["util_max"] for sp in (first, second)] == [0.25, 0.75]
+    assert second.args["queue_hwm"] == 12.0
+    assert {i.parent for i in tr.instants} == {solve.index}
 
 
 def test_null_tracer_counter_is_noop():
-    trace_lib.NULL_TRACER.counter("telemetry/util_max", 1.0)
-    assert trace_lib.NULL_TRACER.counters == ()
+    """The NullTracer's host-sync counter counts nothing, and neither
+    tracer keeps counter tracks any more."""
+    for _ in range(3):
+        trace_lib.NULL_TRACER.host_sync()
+    assert trace_lib.NULL_TRACER.host_syncs == 0
+    tr = obs.Tracer()
+    tr.host_sync()
+    tr.host_sync()
+    assert tr.host_syncs == 2
+    assert not hasattr(trace_lib.NULL_TRACER, "counter")
+    assert not hasattr(tr, "counter")
+
+
+def test_profiler_trace_holds_one_event_per_span_nested_as_the_tree():
+    s, r, cfg = small_case()
+    tr = obs.Tracer()
+    events = profiled(lambda: rank_list_with_stats(
+        s, r, mesh8(), cfg=cfg, seed=1, tracer=tr))
+    assert len(tr.spans) > 7 * 4
+    assert all(e[0].split("/")[0] in ("solve", "frontdoor", "stage",
+                                      "stage-attempt", "driver")
+               for e in events)
+    assert_nested_as_tree(tr, events)
+
+
+def test_end_on_parent_exits_forgotten_children_annotations():
+    """``end`` on a parent closes its still-open children, innermost
+    first, and exits their annotations; so does ``close_all``. A second
+    ``end`` of a closed span changes nothing."""
+    tr = obs.Tracer()
+
+    def forget_children():
+        outer = tr.begin("outer", cat="solve")
+        tr.begin("middle", cat="stage")
+        tr.begin("inner", cat="driver")
+        tr.end(outer)
+        tr.begin("a", cat="solve")
+        tr.begin("b", cat="stage")
+        tr.close_all()
+        closed = [sp.t1 for sp in tr.spans]
+        tr.end(outer, outcome="late")
+        assert [sp.t1 for sp in tr.spans] == closed
+
+    events = profiled(forget_children)
+    assert [e[0] for e in events] == ["solve/outer", "stage/middle",
+                                      "driver/inner", "solve/a", "stage/b"]
+    assert_nested_as_tree(tr, events)
+    outer, middle, inner, a, b = tr.spans
+    assert outer.t1 >= middle.t1 >= inner.t1 >= inner.t0
+    assert a.t1 >= b.t1
+    assert outer.args["outcome"] == "late"
+
+
+def _solve_syncs(cfg, **kw):
+    s, r, _ = small_case()
+    tr = obs.Tracer()
+    _, _, stats = rank_list_with_stats(s, r, mesh8(), cfg=cfg, seed=1,
+                                       tracer=tr, **kw)
+    (solve,) = tr.find(cat="solve")
+    return solve.args["host_syncs"], stats["attempts"]
+
+
+#: blocking syncs of a clean srs_rounds=2 solve: the term_bound
+#: readback, the fingerprint's two copies, one wait per stage (7),
+#: four fatal counters per stage, the 15 stats of the last readback
+CLEAN_SYNCS = 1 + 2 + 7 + 7 * len(resume_lib.FATAL_KEYS) + 15
+
+
+@pytest.mark.parametrize("case", ("clean", "escalated", "injected"))
+def test_host_syncs_counts_every_blocking_readback(case):
+    _, _, cfg = small_case()
+    assert CLEAN_SYNCS == 53
+    if case == "clean":
+        assert _solve_syncs(cfg) == (53, 1)
+    elif case == "escalated":
+        # a real sub overflow at descend@0, twice: each retried attempt
+        # waits once and reads its four fatal counters again
+        syncs, attempts = _solve_syncs(cfg.with_(sub_capacity_slack=0.05))
+        assert attempts == 3
+        assert syncs == 53 + (attempts - 1) * (1 + 4)
+    else:
+        # an injector also validates each stage's output (three copies
+        # per store; two stores after descend@0), so compare with one
+        # that never fires
+        idle, _ = _solve_syncs(cfg, inject=FaultSpec(
+            "overflow", stage="descend", level=5, family="chase"))
+        syncs, attempts = _solve_syncs(cfg, inject=FaultSpec(
+            "overflow", stage="descend", level=0, family="chase"))
+        assert attempts == 2 and idle > 53
+        assert syncs == idle + 1 + 4 + 2 * 3
+
+
+def test_each_committed_attempt_has_dispatch_wait_readback():
+    s, r, cfg = small_case()
+    tr = obs.Tracer()
+    rank_list_with_stats(s, r, mesh8(), cfg=cfg, seed=1, tracer=tr)
+    committed = [a for a in tr.find(cat="stage-attempt")
+                 if a.args["outcome"] == "committed"]
+    assert len(committed) == 7
+    for att in committed:
+        kids = tr.children(att)
+        assert [span_label(k) for k in kids] == [
+            "driver/dispatch", "driver/wait", "driver/readback"]
+        dispatch, wait, _ = kids
+        # wall_s is read on the spans' clock: enqueue (inside dispatch)
+        # plus the device wait
+        assert wait.duration <= att.args["wall_s"]
+        assert att.args["wall_s"] <= dispatch.duration + wait.duration
+    (solve,) = tr.find(cat="solve")
+    last = tr.children(solve)[-1]
+    assert span_label(last) == "driver/readback"
+    assert last.t0 >= max(a.t1 for a in committed)
+
+
+def test_solve_span_encloses_front_door_spans():
+    s, r, cfg = small_case()
+    tr = obs.Tracer()
+    rank_list_with_stats(s, r, mesh8(), cfg=cfg, seed=1, tracer=tr)
+    (solve,) = tr.find(cat="solve")
+    door = [span_label(k) for k in tr.children(solve)][:3]
+    assert door == ["frontdoor/term_bound", "frontdoor/place",
+                    "frontdoor/fingerprint"]
+    first_stage = next(tr.find(cat="stage"))
+    for sp in tr.find(cat="frontdoor"):
+        assert sp.parent == solve.index
+        assert solve.t0 <= sp.t0 <= sp.t1 <= first_stage.t0
 
 
 def test_residual_summary_totals():

@@ -222,23 +222,30 @@ def test_escalation_explained_in_scales_terms():
 
 
 def test_tracer_gets_utilization_annotations():
-    """With a tracer attached, telemetry annotates the span tree: the
-    committed attempt spans carry util_max/util_mean args and the
-    tracer accumulates Perfetto counter samples that export as ph:'C'
-    events."""
+    """With a tracer attached, telemetry annotates the span tree: every
+    committed attempt span carries the stage's util_max, util_mean and
+    queue high-water mark as args, the values its telemetry record
+    holds."""
     succ, rank = instances.gen_list(512, gamma=1.0, seed=1)
     cfg = ListRankConfig(srs_rounds=2, local_contraction=True,
                          telemetry=True)
-    tr = obs.Tracer(meta={"name": "tele-test"})
-    rank_list_with_stats(succ, rank, sim_mesh(8), cfg=cfg, seed=1,
-                         tracer=tr)
+    tr = obs.Tracer()
+    _, _, stats = rank_list_with_stats(succ, rank, sim_mesh(8), cfg=cfg,
+                                       seed=1, tracer=tr)
     annotated = [s for s in tr.spans if "util_max" in s.args]
     assert annotated
     assert all(np.isfinite(s.args["util_max"]) for s in annotated)
-    assert any(name.startswith("telemetry/") for name, _, _ in tr.counters)
-    doc = obs.chrome_trace(tr)
-    cs = [e for e in doc["traceEvents"] if e["ph"] == "C"]
-    assert cs and all(e["cat"] == "telemetry" for e in cs)
+    committed = [s for s in tr.find(cat="stage-attempt")
+                 if s.args["outcome"] == "committed"]
+    assert committed == annotated
+    records = stats["telemetry"]["stages"]
+    assert [s.args["stage"] for s in committed] == [r["label"]
+                                                    for r in records]
+    for span, rec in zip(committed, records):
+        tele = obs.StageRecord.from_json(rec).tele
+        assert span.args["util_max"] == obs.utilization(tele)["util_max"]
+        assert span.args["util_mean"] == obs.utilization(tele)["util_mean"]
+        assert span.args["queue_hwm"] == float(tele.get("queue_hwm", 0))
 
 
 def test_metrics_ingest_telemetry():
