@@ -451,7 +451,9 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
     §2.6 predicted time, the final stats readback, and checkpoint
     save/restore — and ingests the final ``host_stats`` into the
     tracer's metrics registry. The ``solve`` span ends with
-    ``host_syncs``, the blocking host<->device syncs the solve made.
+    ``host_syncs``, the blocking host<->device syncs the solve made,
+    and ``recursion_skipped``: 1 when contraction left no live link
+    and the driver went straight from ``prep`` to ``post``.
     Host-side only; the traced programs are bit-identical with tracing
     on or off.
     """
@@ -523,6 +525,7 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
                host_syncs=tr.host_syncs - syncs_before)
         raise
     tr.end(solve_span, outcome="ok", attempts=host_stats["attempts"],
+           recursion_skipped=host_stats["recursion_skipped"],
            host_syncs=tr.host_syncs - syncs_before)
     if "telemetry" in host_stats and estimate is not None:
         # back-test the sampled-splitter DKW margins against the skew

@@ -9,10 +9,15 @@ level boundary as a checkpointable pytree:
     prep -> descend@0 .. descend@L-1 -> base@L -> ascend@L-1 .. ascend@0 -> post
     prep -> pd@0 -> post                                   (plain doubling)
 
-Because the staged program is built from the exact functions the
-monolithic program composes, a straight-through staged solve is
-op-for-op identical to the monolithic one — the golden bit-identity
-pins (tests/golden) hold for both by construction.
+The staged program is built from the exact functions the monolithic
+program composes, so a staged solve returns the monolithic one's bits —
+the golden bit-identity pins (tests/golden) hold for both by
+construction. The stage counts need not match: when the instance that
+``prep`` hands on holds no live link (every valid element a self-loop,
+as on one PE after local contraction), the driver goes straight from
+``prep`` to ``post``. Sink ranking is the identity on such an
+instance, so the recursion stages would write back their input
+unchanged; their stats read 0 and ``recursion_skipped`` reads 1.
 
 What the explicit boundary state buys (DESIGN.md §11):
 
@@ -41,7 +46,10 @@ the PE axes on axis 0 (per-PE stats ride as (1,)-per-PE slices):
     is_subs:  per descended level, the sub-membership mask
     is_terms: per descended level, the level's terminal mask
     stats:    per-PE partial stat counters (psum'd once, in post)
-    forced:   [srs only, until descend@0] forced-ruler mask
+    forced:   [srs only, prep boundary only] forced-ruler mask
+    live:     [prep boundary only] per-PE count of the top store's live
+              links (valid, not a self-loop); 0 everywhere skips the
+              recursion
     rep/aux:  [local_contraction only] restoration inputs (§2.3)
 """
 from __future__ import annotations
@@ -78,6 +86,15 @@ FATAL_KEYS = ("dropped", "sub_overflow", "store_miss", "undelivered")
 #: restricted to the capacity-exclusive solver families).
 FAMILY_STAT = {"chase": "dropped", "sub": "sub_overflow",
                "gather": "undelivered"}
+
+#: boundary-state keys of the prep boundary alone: whichever stage
+#: follows prep (a recursion stage, or post on a skip) drops them
+PREP_ONLY = ("forced", "live")
+
+
+def _drop_prep_only(state):
+    """``state`` without its :data:`PREP_ONLY` keys."""
+    return {k: v for k, v in state.items() if k not in PREP_ONLY}
 
 
 class SolveExhausted(RuntimeError):
@@ -238,6 +255,8 @@ def _prep_body(succ, rank, *, plan, cfg, spec0, m):
                                                   stats)
             state["forced"] = is_term0
     state["stores"] = (st,)
+    state["live"] = jnp.reshape(
+        jnp.sum(st.valid & (st.succ != st.ids)).astype(jnp.int32), (1,))
     state["takes"] = ()
     state["is_subs"] = ()
     state["is_terms"] = ()
@@ -259,7 +278,7 @@ def _descend_body(state, seed, *, plan, cfg, spec, level, m):
     forced = state.get("forced") if level == 0 else None
     st, sub, take, is_sub, is_term, stats = srs_lib.descend_level(
         plan, cfg, spec, owner_of, st, key, level, stats, forced)
-    out = {k: v for k, v in state.items() if k != "forced"}
+    out = _drop_prep_only(state)
     out["stores"] = state["stores"][:-1] + (st, sub)
     out["takes"] = state["takes"] + (take,)
     out["is_subs"] = state["is_subs"] + (is_sub,)
@@ -275,7 +294,7 @@ def _base_body(state, *, plan, cfg, spec, m):
     stats = _tele_seed(_stats_in(state["stats"]), plan)
     st, stats = srs_lib.base_level(plan, cfg, spec, _owner_fn(m),
                                    state["stores"][-1], stats)
-    out = dict(state)
+    out = _drop_prep_only(state)
     out["stores"] = state["stores"][:-1] + (st,)
     stats, tele = _tele_pop(stats, plan)
     out["stats"] = _stats_out(stats)
@@ -315,7 +334,7 @@ def _pd_body(state, *, plan, cfg, spec0, spec_base, m):
         # PD requests ride the gather-family mailboxes (req/resp caps).
         upd["telemetry"] = {"gather": pst["telemetry"]}
     stats = _merge(stats, upd)
-    out = dict(state)
+    out = _drop_prep_only(state)
     out["stores"] = state["stores"][:-1] + (st,)
     stats, tele = _tele_pop(stats, plan)
     out["stats"] = _stats_out(stats)
@@ -405,13 +424,12 @@ def boundary_template(sched, idx: int, cfg: ListRankConfig, specs, m: int,
     wdt = jnp.dtype(weight_dtype)
     caps = [m]                      # store-capacity stack
     take_caps: list[int] = []
-    has_forced = cfg.algorithm != "doubling"
+    at_prep = True                  # PREP_ONLY keys: until the next stage
     for stage in sched[1:idx]:
+        at_prep = False
         if stage.kind == "descend":
             take_caps.append(specs[stage.level].cap_sub)
             caps.append(specs[stage.level].cap_sub)
-            if stage.level == 0:
-                has_forced = False
         elif stage.kind == "ascend":
             caps.pop()
             take_caps.pop()
@@ -428,8 +446,10 @@ def boundary_template(sched, idx: int, cfg: ListRankConfig, specs, m: int,
                                dense=(j == 0))
 
     state = {}
-    if has_forced:
-        state["forced"] = arr(m, jnp.bool_)
+    if at_prep:
+        if cfg.algorithm != "doubling":
+            state["forced"] = arr(m, jnp.bool_)
+        state["live"] = jax.ShapeDtypeStruct((p,), jnp.int32)
     state["stores"] = tuple(store_t(j, c) for j, c in enumerate(caps))
     state["takes"] = tuple(arr(c, jnp.int32) for c in take_caps)
     # the level-k masks cover the store that was live when level k
@@ -514,6 +534,12 @@ def _apply_corruption(state, spec: faults_lib.FaultSpec, mesh, plan, m: int,
     return out
 
 
+def _live_total(state, tr=trace_lib.NULL_TRACER) -> int:
+    """Live links (valid, not a self-loop) of the prep boundary's top
+    store, over every PE."""
+    return int(np.sum(np.asarray(_device_get(state["live"], tr))))
+
+
 def _fatal_totals(stats, tr=trace_lib.NULL_TRACER) -> dict:
     """Global fatal-stat totals from a boundary state's per-PE stats (or
     post's already-reduced dict)."""
@@ -539,7 +565,11 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     :class:`~repro.core.listrank.faults.FaultInjector`, FaultSpec, or
     sequence of FaultSpecs) drives the recovery paths deterministically;
     ``stage_counters`` records each executed stage's traced collective
-    counts in ``host_stats["stage_collectives"]``; ``tracer`` (a
+    counts in ``host_stats["stage_collectives"]``. At the prep boundary
+    the driver reads the per-PE ``live`` counts (one scalar read); when
+    every PE reads 0 it goes straight to ``post``
+    (``host_stats["recursion_skipped"]`` 1, ``stage_log`` ``("prep",
+    "post")``). ``tracer`` (a
     :class:`repro.obs.Tracer`) records the flight-recorder span tree —
     the ``frontdoor/fingerprint`` span, one ``stage`` span per schedule
     slot with one nested ``stage-attempt`` span per execution (each with
@@ -573,6 +603,7 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     stage_collectives: list[tuple] = []
     tele_records: list[tele_lib.StageRecord] = []
     crashes = 0
+    recursion_skipped = 0
     if supervisor is not None:
         supervisor.tracer = tr
 
@@ -618,7 +649,19 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                 "m": m, "algorithm": cfg.algorithm, "attempts": attempts,
                 "scales_log": list(scales_log),
                 "scales": [dataclasses.asdict(s) for s in level_scales],
-                "weight_dtype": str(wdt)}
+                "weight_dtype": str(wdt),
+                "recursion_skipped": recursion_skipped}
+
+    def leave_prep(state, idx, live):
+        """(state, idx) to run next from the prep boundary: straight to
+        post when no PE holds a live link. Sink ranking is the identity
+        on such an instance, so the recursion would hand post this very
+        store."""
+        nonlocal recursion_skipped
+        recursion_skipped = int(live == 0)
+        if live:
+            return state, idx
+        return _drop_prep_only(state), len(sched) - 1
 
     def try_restore():
         """(state, idx, prev_fatal) from the supervisor's latest valid
@@ -632,17 +675,22 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
         meta = supervisor.latest_meta()
         if not meta or meta.get("fingerprint") != fp:
             return None
-        nonlocal level_scales, attempts, scales_log
+        nonlocal level_scales, attempts, scales_log, recursion_skipped
         level_scales = tuple(tuner.CapacityScales(**d)
                              for d in meta["scales"])
         attempts = int(meta["attempts"])
         scales_log = list(meta["scales_log"])
+        recursion_skipped = int(meta["recursion_skipped"])
+        idx = int(meta["idx"])
         specs = build_level_specs(level_scales)
-        like = boundary_template(sched, meta["idx"], cfg, specs, m, p,
+        like = boundary_template(sched, idx, cfg, specs, m, p,
                                  jnp.dtype(meta["weight_dtype"]))
         state, _ = supervisor.restore(like, state_shardings(mesh, plan, like))
-        supervisor.stats["resumed_from"] = int(meta["idx"])
-        return state, int(meta["idx"]), _fatal_totals(state["stats"], tr)
+        supervisor.stats["resumed_from"] = idx
+        prev = _fatal_totals(state["stats"], tr)
+        if "live" in state:         # the prep boundary: decide again
+            state, idx = leave_prep(state, idx, _live_total(state, tr))
+        return state, idx, prev
 
     state, idx = None, 0
     prev_fatal = {k: 0 for k in FATAL_KEYS}
@@ -716,7 +764,7 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
             if restored is not None:
                 state, idx, prev_fatal = restored
             else:
-                state, idx = None, 0
+                state, idx, recursion_skipped = None, 0, 0
                 prev_fatal = {k: 0 for k in FATAL_KEYS}
             stage_span_idx = -1  # reopen a fresh stage span after rewind
             continue
@@ -725,6 +773,8 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
             att.annotate(**stage_prediction(runner, args))
         with tr.span("readback", cat="driver"):
             fatal = _fatal_totals(fatal_src, tr)
+            if stage.kind == "prep":
+                live = _live_total(out, tr)
         delta = {k: fatal[k] - prev_fatal[k] for k in FATAL_KEYS}
         fam = (injector.overflow_after(stage.kind, stage.level)
                if injector is not None else None)
@@ -805,6 +855,8 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
         if supervisor is not None:
             supervisor.note_stage_time(dt)
             supervisor.boundary(idx, state, make_meta(idx))
+        if stage.kind == "prep":
+            state, idx = leave_prep(state, idx, live)
         if injector is not None and injector.preempt_after(stage.kind,
                                                            stage.level):
             injected_log.append(f"preempt:{stage.label}")
@@ -822,6 +874,7 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
         host_stats = {k: int(_device_get(v, tr))
                       for k, v in dev_stats.items()}
     host_stats["attempts"] = attempts
+    host_stats["recursion_skipped"] = recursion_skipped
     host_stats["scales_log"] = ";".join(scales_log)
     host_stats["stage_log"] = tuple(stage_log)
     rec = (dict(supervisor.stats) if supervisor is not None else

@@ -78,6 +78,8 @@ STAT_HELP = {
     "fixup_msgs": "\u00a72.3 restoration fixup messages",
     "max_queue": "peak chase queue occupancy (gauge)",
     "attempts": "driver attempts (1 + capacity escalations)",
+    "recursion_skipped": "solves sent from prep straight to post: no live "
+                         "link left after contraction (0 or 1)",
 }
 
 

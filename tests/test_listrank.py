@@ -1,6 +1,14 @@
 """List-ranking correctness on a single-device mesh (full code path —
 routing, spawning, recursion, contraction — with p=1 self-sends) plus
-hypothesis property tests. Multi-PE runs live in test_listrank_multi."""
+hypothesis property tests. On one PE local contraction leaves nothing
+for the recursion (the driver skips it), so configurations with
+contraction run on four virtual PEs, where the recursion still runs.
+Multi-PE runs on devices live in test_listrank_multi."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -9,16 +17,27 @@ from _hypothesis_compat import HealthCheck, given, settings, st
 from repro.launch.mesh import make_mesh
 from repro.core.listrank import (IndirectionSpec, ListRankConfig, analysis,
                                  instances, rank_list_seq,
-                                 rank_list_with_stats)
+                                 rank_list_with_stats, sim_mesh)
+from repro.core.listrank import transport
 
 
 def mesh1():
     return make_mesh((1,), ("pe",))
 
 
-def run_and_check(succ, rank, cfg, **kw):
+def mesh_for(cfg):
+    """Where ``cfg`` reaches the recursion: four virtual PEs with local
+    contraction, one device without. The Pallas kernels need a device
+    mesh, so a Pallas contraction stays on one PE and skips."""
+    if cfg.local_contraction and not cfg.use_pallas:
+        return sim_mesh(4)
+    return mesh1()
+
+
+def run_and_check(succ, rank, cfg, mesh=None, **kw):
     s_ref, r_ref = rank_list_seq(succ, rank)
-    s, r, stats = rank_list_with_stats(succ, rank, mesh1(), cfg=cfg, **kw)
+    s, r, stats = rank_list_with_stats(succ, rank, mesh or mesh1(), cfg=cfg,
+                                       **kw)
     np.testing.assert_array_equal(np.asarray(s), s_ref)
     np.testing.assert_array_equal(np.asarray(r), r_ref)
     return stats
@@ -50,21 +69,29 @@ VARIANTS = {
 @pytest.mark.parametrize("variant", list(VARIANTS))
 def test_variants_random_list(variant):
     succ, rank = instances.gen_list(256, gamma=1.0, seed=3)
-    run_and_check(succ, rank, VARIANTS[variant])
+    cfg = VARIANTS[variant]
+    mesh = mesh_for(cfg)
+    stats = run_and_check(succ, rank, cfg, mesh)
+    one_pe = not transport.is_sim(mesh)
+    assert stats["recursion_skipped"] == (cfg.local_contraction and one_pe)
 
 
 @pytest.mark.parametrize("gamma", [0.0, 0.3, 1.0])
 def test_locality_instances(gamma):
     succ, rank = instances.gen_list(512, gamma=gamma, seed=5)
-    run_and_check(succ, rank, BASE.with_(local_contraction=True))
+    stats = run_and_check(succ, rank, BASE.with_(local_contraction=True),
+                          sim_mesh(4))
+    assert stats["recursion_skipped"] == 0
 
 
 def test_multilist_and_weighted():
     succ, rank = instances.gen_random_lists(512, num_lists=9, seed=7,
                                             weighted=True)
     stats = run_and_check(succ, rank, BASE.with_(srs_rounds=2,
-                                                 local_contraction=True))
+                                                 local_contraction=True),
+                          sim_mesh(4))
     assert stats["dropped"] == 0
+    assert stats["recursion_skipped"] == 0
 
 
 def test_euler_tour_instance():
@@ -95,9 +122,11 @@ def test_packed_unpacked_bit_identical(make):
     output bits to the unpacked exchange, on every instance."""
     succ, rank = make()
     for cfg in (BASE, BASE.with_(srs_rounds=2, local_contraction=True)):
-        s_p, r_p, _ = rank_list_with_stats(succ, rank, mesh1(), cfg=cfg)
+        mesh = mesh_for(cfg)
+        s_p, r_p, st_p = rank_list_with_stats(succ, rank, mesh, cfg=cfg)
         s_u, r_u, _ = rank_list_with_stats(
-            succ, rank, mesh1(), cfg=cfg.with_(wire_packing=False))
+            succ, rank, mesh, cfg=cfg.with_(wire_packing=False))
+        assert st_p["recursion_skipped"] == 0
         np.testing.assert_array_equal(np.asarray(s_p), np.asarray(s_u))
         np.testing.assert_array_equal(
             np.asarray(r_p).view(np.int32), np.asarray(r_u).view(np.int32))
@@ -120,11 +149,13 @@ def test_singletons_only():
        avoid_rev=st.booleans())
 def test_property_random_forests(n, nl, seed, srs_rounds, contract,
                                  avoid_rev):
-    nl = min(nl, n)
-    succ, rank = instances.gen_random_lists(n, num_lists=nl, seed=seed)
     cfg = BASE.with_(srs_rounds=srs_rounds, local_contraction=contract,
                      avoid_reversal=avoid_rev)
-    run_and_check(succ, rank, cfg)
+    mesh = mesh_for(cfg)
+    n -= n % mesh.size              # whole blocks per PE
+    nl = min(nl, n)
+    succ, rank = instances.gen_random_lists(n, num_lists=nl, seed=seed)
+    run_and_check(succ, rank, cfg, mesh)
 
 
 @settings(max_examples=10, deadline=None,
@@ -164,14 +195,12 @@ def test_retry_on_tiny_capacity():
     np.testing.assert_array_equal(np.asarray(r), r_ref)
 
 
-@pytest.mark.parametrize("cfg", [
-    ListRankConfig(),
-    ListRankConfig(local_contraction=False),
-    ListRankConfig(algorithm="doubling"),
-], ids=["default", "chase", "doubling"])
-def test_lower_stages_lowers_the_solve_programs(cfg, monkeypatch):
+def check_lowering(cfg, mesh):
     """lower_stages, from shapes alone, lowers the very programs (and
-    argument placements) that a first-attempt solve runs."""
+    argument placements and pytrees: jit drops an unused leaf from the
+    text) that a first-attempt solve of a random list on ``mesh`` runs:
+    every stage where the recursion runs, prep and post where the
+    driver skips it."""
     from repro.core.listrank import api, resume
 
     n = 1 << 10
@@ -181,14 +210,59 @@ def test_lower_stages_lowers_the_solve_programs(cfg, monkeypatch):
 
     def spy(*a):
         runner, args = stage_call(*a)
-        ran.append(runner.lower(*args).as_text())
+        lw = runner.lower(*args)
+        ran.append((lw.in_tree, lw.as_text()))
         return runner, args
 
-    monkeypatch.setattr(resume, "_stage_call", spy)
-    run_and_check(succ, rank, cfg)
-    monkeypatch.undo()
-    lowered = api.lower_stages(n, mesh1(), cfg=cfg)
-    assert [lw.as_text() for _, lw in lowered] == ran
+    resume._stage_call = spy
+    try:
+        stats = run_and_check(succ, rank, cfg, mesh)
+    finally:
+        resume._stage_call = stage_call
+    lowered = api.lower_stages(n, mesh, cfg=cfg)
+    labels = [st.label for st in resume.schedule_for(cfg)]
+    assert [lbl for lbl, _ in lowered] == labels
+    program = {lbl: (lw.in_tree, lw.as_text()) for lbl, lw in lowered}
+    if stats["recursion_skipped"]:
+        assert stats["stage_log"] == ("prep", "post")
+        assert [program["prep"], program["post"]] == ran
+    else:
+        assert list(stats["stage_log"]) == labels
+        assert [program[lbl] for lbl in labels] == ran
+    return stats
+
+
+@pytest.mark.parametrize("kw,pes", [
+    ({}, 1),
+    ({"local_contraction": False}, 1),
+    ({"algorithm": "doubling"}, 1),
+    ({"algorithm": "doubling", "local_contraction": False}, 1),
+    ({"srs_rounds": 0, "local_contraction": False}, 1),
+    ({}, 4),
+], ids=["default", "chase", "doubling", "doubling_chase", "srs0_chase",
+        "default_4pe"])
+def test_lower_stages_lowers_the_solve_programs(kw, pes):
+    """One PE with contraction skips the recursion; without contraction,
+    and with contraction on four devices (a subprocess, since the device
+    count is fixed before jax starts), every stage is compared."""
+    cfg = ListRankConfig(**kw)
+    if pes == 1:
+        stats = check_lowering(cfg, mesh1())
+        assert stats["recursion_skipped"] == cfg.local_contraction
+        return
+    here = pathlib.Path(__file__).parent
+    code = (f"import sys; sys.path[:0] = [{str(here.parent / 'src')!r}, "
+            f"{str(here)!r}]\n"
+            "from test_listrank import ListRankConfig, check_lowering, "
+            "make_mesh\n"
+            f"stats = check_lowering(ListRankConfig(**{kw!r}), "
+            f"make_mesh(({pes},), ('pe',)))\n"
+            "assert stats['recursion_skipped'] == 0\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={pes}")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
 
 
 def test_lower_stages_refuses_capacity_estimation():

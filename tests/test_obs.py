@@ -465,23 +465,25 @@ def _solve_syncs(cfg, **kw):
 
 #: blocking syncs of a clean srs_rounds=2 solve: the term_bound
 #: readback, the fingerprint's two copies, one wait per stage (7),
-#: four fatal counters per stage, the 15 stats of the last readback
-CLEAN_SYNCS = 1 + 2 + 7 + 7 * len(resume_lib.FATAL_KEYS) + 15
+#: four fatal counters per stage, prep's live-link count, the 15 stats
+#: of the last readback
+CLEAN_SYNCS = 1 + 2 + 7 + 7 * len(resume_lib.FATAL_KEYS) + 1 + 15
 
 
-@pytest.mark.parametrize("case", ("clean", "escalated", "injected"))
+@pytest.mark.parametrize("case", ("clean", "escalated", "injected",
+                                  "skipped"))
 def test_host_syncs_counts_every_blocking_readback(case):
     _, _, cfg = small_case()
-    assert CLEAN_SYNCS == 53
+    assert CLEAN_SYNCS == 54
     if case == "clean":
-        assert _solve_syncs(cfg) == (53, 1)
+        assert _solve_syncs(cfg) == (54, 1)
     elif case == "escalated":
         # a real sub overflow at descend@0, twice: each retried attempt
         # waits once and reads its four fatal counters again
         syncs, attempts = _solve_syncs(cfg.with_(sub_capacity_slack=0.05))
         assert attempts == 3
-        assert syncs == 53 + (attempts - 1) * (1 + 4)
-    else:
+        assert syncs == 54 + (attempts - 1) * (1 + 4)
+    elif case == "injected":
         # an injector also validates each stage's output (three copies
         # per store; two stores after descend@0), so compare with one
         # that never fires
@@ -489,8 +491,19 @@ def test_host_syncs_counts_every_blocking_readback(case):
             "overflow", stage="descend", level=5, family="chase"))
         syncs, attempts = _solve_syncs(cfg, inject=FaultSpec(
             "overflow", stage="descend", level=0, family="chase"))
-        assert attempts == 2 and idle > 53
+        assert attempts == 2 and idle > 54
         assert syncs == idle + 1 + 4 + 2 * 3
+    else:
+        # one PE with local contraction: prep and post alone
+        s, r, _ = small_case()
+        tr = obs.Tracer()
+        _, _, stats = rank_list_with_stats(
+            s, r, sim_mesh(1), cfg=cfg.with_(local_contraction=True),
+            tracer=tr)
+        (solve,) = tr.find(cat="solve")
+        assert stats["stage_log"] == ("prep", "post")
+        assert solve.args["recursion_skipped"] == 1
+        assert solve.args["host_syncs"] == 1 + 2 + 2 + 2 * 4 + 1 + 15
 
 
 def test_each_committed_attempt_has_dispatch_wait_readback():
