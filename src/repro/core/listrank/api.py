@@ -452,8 +452,10 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
     save/restore — and ingests the final ``host_stats`` into the
     tracer's metrics registry. The ``solve`` span ends with
     ``host_syncs``, the blocking host<->device syncs the solve made,
-    and ``recursion_skipped``: 1 when contraction left no live link
-    and the driver went straight from ``prep`` to ``post``.
+    ``recursion_skipped``: 1 when contraction left no live link and
+    the driver went straight from ``prep`` to ``post``, ``live_links``:
+    the links contraction left, and ``sub_capacity``: the slots of the
+    recursion's sub-stores.
     Host-side only; the traced programs are bit-identical with tracing
     on or off.
     """
@@ -526,6 +528,8 @@ def rank_list_with_stats(succ, rank, mesh, pe_axes: Sequence[str] | None = None,
         raise
     tr.end(solve_span, outcome="ok", attempts=host_stats["attempts"],
            recursion_skipped=host_stats["recursion_skipped"],
+           live_links=host_stats["live_links"],
+           sub_capacity=host_stats["sub_capacity"],
            host_syncs=tr.host_syncs - syncs_before)
     if "telemetry" in host_stats and estimate is not None:
         # back-test the sampled-splitter DKW margins against the skew

@@ -18,6 +18,9 @@ as on one PE after local contraction), the driver goes straight from
 ``prep`` to ``post``. Sink ranking is the identity on such an
 instance, so the recursion stages would write back their input
 unchanged; their stats read 0 and ``recursion_skipped`` reads 1.
+``live_links`` counts what ``prep`` handed on (0 exactly on a skip) and
+``sub_capacity`` the sub-store slots the recursion ran with, the room
+that ``sub_size`` fills.
 
 What the explicit boundary state buys (DESIGN.md §11):
 
@@ -569,7 +572,10 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     the driver reads the per-PE ``live`` counts (one scalar read); when
     every PE reads 0 it goes straight to ``post``
     (``host_stats["recursion_skipped"]`` 1, ``stage_log`` ``("prep",
-    "post")``). ``tracer`` (a
+    "post")``). ``host_stats["live_links"]`` is that count over every
+    PE, and ``host_stats["sub_capacity"]`` the slots of the sub-stores
+    the recursion built (every descended level, every PE, at the final
+    attempt's capacities; 0 where none was built). ``tracer`` (a
     :class:`repro.obs.Tracer`) records the flight-recorder span tree —
     the ``frontdoor/fingerprint`` span, one ``stage`` span per schedule
     slot with one nested ``stage-attempt`` span per execution (each with
@@ -604,6 +610,7 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
     tele_records: list[tele_lib.StageRecord] = []
     crashes = 0
     recursion_skipped = 0
+    live_links = 0
     if supervisor is not None:
         supervisor.tracer = tr
 
@@ -650,15 +657,16 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                 "scales_log": list(scales_log),
                 "scales": [dataclasses.asdict(s) for s in level_scales],
                 "weight_dtype": str(wdt),
-                "recursion_skipped": recursion_skipped}
+                "recursion_skipped": recursion_skipped,
+                "live_links": live_links}
 
     def leave_prep(state, idx, live):
         """(state, idx) to run next from the prep boundary: straight to
         post when no PE holds a live link. Sink ranking is the identity
         on such an instance, so the recursion would hand post this very
         store."""
-        nonlocal recursion_skipped
-        recursion_skipped = int(live == 0)
+        nonlocal recursion_skipped, live_links
+        recursion_skipped, live_links = int(live == 0), int(live)
         if live:
             return state, idx
         return _drop_prep_only(state), len(sched) - 1
@@ -676,11 +684,13 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
         if not meta or meta.get("fingerprint") != fp:
             return None
         nonlocal level_scales, attempts, scales_log, recursion_skipped
+        nonlocal live_links
         level_scales = tuple(tuner.CapacityScales(**d)
                              for d in meta["scales"])
         attempts = int(meta["attempts"])
         scales_log = list(meta["scales_log"])
         recursion_skipped = int(meta["recursion_skipped"])
+        live_links = int(meta["live_links"])
         idx = int(meta["idx"])
         specs = build_level_specs(level_scales)
         like = boundary_template(sched, idx, cfg, specs, m, p,
@@ -764,7 +774,7 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
             if restored is not None:
                 state, idx, prev_fatal = restored
             else:
-                state, idx, recursion_skipped = None, 0, 0
+                state, idx, recursion_skipped, live_links = None, 0, 0, 0
                 prev_fatal = {k: 0 for k in FATAL_KEYS}
             stage_span_idx = -1  # reopen a fresh stage span after rewind
             continue
@@ -875,6 +885,9 @@ def run_staged(succ_d, rank_d, *, mesh, plan, cfg: ListRankConfig, m: int,
                       for k, v in dev_stats.items()}
     host_stats["attempts"] = attempts
     host_stats["recursion_skipped"] = recursion_skipped
+    host_stats["live_links"] = live_links
+    host_stats["sub_capacity"] = 0 if recursion_skipped else p * sum(
+        specs[st.level].cap_sub for st in sched if st.kind == "descend")
     host_stats["scales_log"] = ";".join(scales_log)
     host_stats["stage_log"] = tuple(stage_log)
     rec = (dict(supervisor.stats) if supervisor is not None else

@@ -80,6 +80,10 @@ STAT_HELP = {
     "attempts": "driver attempts (1 + capacity escalations)",
     "recursion_skipped": "solves sent from prep straight to post: no live "
                          "link left after contraction (0 or 1)",
+    "live_links": "links (valid, not a self-loop) that contraction left "
+                  "for the recursion, over every PE",
+    "sub_capacity": "slots of the recursion sub-stores (every descended "
+                    "level and PE), the room sub_size fills",
 }
 
 

@@ -166,7 +166,8 @@ class MetricsRegistry:
 # --------------------------------------------------------------------------
 
 #: host_stats keys that are levels, not event counts.
-GAUGE_KEYS = ("max_queue", "sub_size", "rulers", "forest_edges")
+GAUGE_KEYS = ("max_queue", "sub_size", "rulers", "forest_edges",
+              "live_links", "sub_capacity")
 
 
 def _stat_help() -> dict:

@@ -6,13 +6,16 @@ driver goes from ``prep`` straight to ``post``. These tests pin that
 the skip returns the bytes of the full recursion (``api._solve_sharded``
 runs every level whatever the instance), that it engages exactly when
 every list stays inside one PE's block, and that a solve restored at
-the prep boundary decides alike.
+the prep boundary decides alike. They also pin what the solve reports
+of that count (``live_links``) and of the sub-stores the recursion
+built (``sub_capacity``).
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.listrank import (FaultSpec, ListRankConfig, api, instances,
                                  rank_list_seq, rank_list_with_stats,
                                  sim_mesh)
@@ -147,6 +150,41 @@ def test_no_live_link_skips_without_contraction():
         st.label for st in resume_lib.schedule_for(cfg))
 
 
+@pytest.mark.parametrize("p,gamma", [(4, 0.0), (4, 1.0), (1, 0.0),
+                                     (1, 1.0)])
+def test_live_links_counts_what_contraction_leaves(p, gamma):
+    """At gamma 0 each PE's block is one run of the list, so contraction
+    leaves the p-1 links between blocks; at gamma 1 most links cross
+    PEs; on one PE none is left and the recursion is skipped. Neither
+    count costs a host sync: 54 on the full schedule, 29 on the skip."""
+    succ, rank = instances.gen_list(2048, gamma=gamma, seed=8)
+    cfg = ListRankConfig()
+    tr = obs.Tracer()
+    sf, rf, stats = rank_list_with_stats(succ, rank, sim_mesh(p), cfg=cfg,
+                                         seed=3, tracer=tr)
+    assert_matches_oracle(succ, rank, (sf, rf))
+    (solve,) = tr.find(cat="solve")
+    assert solve.args["live_links"] == stats["live_links"]
+    assert solve.args["sub_capacity"] == stats["sub_capacity"]
+    if p == 1:
+        assert stats["recursion_skipped"] == 1
+        assert stats["live_links"] == stats["sub_capacity"] == 0
+        assert solve.args["host_syncs"] == 29
+        return
+    assert stats["recursion_skipped"] == 0
+    assert solve.args["host_syncs"] == 54
+    if gamma == 0.0:
+        assert stats["live_links"] == p - 1
+    else:
+        assert stats["live_links"] > 100 * p
+    _, _, _, plan, rcfg, m = api._resolve(2048, sim_mesh(p), None, cfg,
+                                          None)
+    specs = api.build_specs(rcfg, plan, m, 2048, term_bound=1)
+    assert stats["sub_capacity"] == p * sum(
+        specs[k].cap_sub for k in range(cfg.srs_rounds))
+    assert stats["sub_capacity"] >= stats["sub_size"] > 0
+
+
 def sup(tmp_path):
     return SolveSupervisor(SolveSupervisorConfig(
         ckpt_dir=str(tmp_path / "ckpt")))
@@ -224,5 +262,8 @@ def test_post_boundary_restore_of_a_full_solve(tmp_path, monkeypatch,
     assert stats["stage_log"] == labels[:-1] + ("post!InjectedFault",
                                                 "post")
     assert stats["recursion_skipped"] == 0
+    # the restored solve reports prep's count from the checkpoint
+    assert stats["live_links"] == fresh[2]["live_links"] > 0
+    assert stats["sub_capacity"] == fresh[2]["sub_capacity"]
     assert_bytes_equal((sf, rf), [np.asarray(a) for a in fresh[:2]])
     assert_matches_oracle(succ, rank, (sf, rf))
